@@ -11,11 +11,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.api import Problem, Schedule, Session, Topology
+from repro.compat import enable_compile_cache
 from repro.core.dual import dual_value, ridge_dual_optimum
 from repro.data.synthetic import gaussian_regression
 
 
 def main():
+    enable_compile_cache()
     X, y = gaussian_regression(m=512, d=64)
     problem = Problem(X, y, loss="squared", lam=0.05)
 

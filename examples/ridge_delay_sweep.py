@@ -6,6 +6,7 @@ runs of CoCoA (star sessions) on ridge regression.
     PYTHONPATH=src python examples/ridge_delay_sweep.py
 """
 from repro.api import Problem, Schedule, Session, Topology
+from repro.compat import enable_compile_cache
 from repro.core.delay import optimal_h
 from repro.core.dual import duality_gap
 from repro.data.synthetic import gaussian_regression
@@ -15,6 +16,7 @@ BUDGET = 2.0  # seconds of simulated wall-clock
 
 
 def main():
+    enable_compile_cache()
     X, y = gaussian_regression(m=600, d=100)
     m = X.shape[0]
     problem = Problem.ridge(X, y, lam=LAM)

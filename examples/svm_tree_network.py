@@ -8,6 +8,7 @@ import jax
 import numpy as np
 
 from repro.api import Problem, Schedule, Session, Topology
+from repro.compat import enable_compile_cache
 from repro.data.synthetic import gaussian_classification
 
 LAM = 0.02
@@ -16,6 +17,7 @@ SLOW = 1e5 * T_LP   # root-link delay (paper Fig. 3 regime)
 
 
 def main():
+    enable_compile_cache()
     X, y = gaussian_classification(m=1024, d=64)
     problem = Problem.svm(X, y, lam=LAM, smoothing=1.0)
     key = jax.random.PRNGKey(1)

@@ -12,6 +12,7 @@ swaps in a tiny config for CI (seconds, any machine).
 """
 import argparse
 
+from repro.compat import enable_compile_cache
 from repro.configs.base import ModelConfig
 from repro.launch.train import train
 
@@ -50,6 +51,7 @@ CFG_SMOKE = ModelConfig(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)

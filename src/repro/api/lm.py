@@ -41,6 +41,7 @@ from repro.analysis import plan_check, trace_guard as guard_mod
 from repro.api.schedule import Schedule
 from repro.api.topology import Topology
 from repro.core.engine import lm as lm_mod
+from repro.core.engine import mesh as mesh_mod
 from repro.core.engine import plan as plan_mod
 from repro.core.engine.method import get_method
 from repro.data.lm import lm_batch
@@ -141,9 +142,9 @@ class LMSession:
                 "dim is sharded over the sync axes and every combine is a "
                 "mesh all-reduce; compile with backend='mesh' "
                 f"(got {backend!r})")
-        if mesh is None:
-            from repro.launch.mesh import make_host_mesh
-            mesh = make_host_mesh()
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh() if mesh is None else \
+            mesh_mod.auto_axes(mesh)
         axes = lm_mod.present_axes(mesh, tuple(sync_axes))
         sizes = tuple(lm_mod.axis_size(mesh, a) for a in axes)  # bottom-up
         if topology is None:

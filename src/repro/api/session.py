@@ -234,12 +234,14 @@ class Session:
                     f"{sizes}, have {have} (set "
                     "XLA_FLAGS=--xla_force_host_platform_device_count=N on "
                     "CPU, or pass mesh=)")
-            mesh = jax.make_mesh(tuple(sizes), names,
-                                 devices=jax.devices()[:need])
+            mesh = mesh_mod.make_mesh(sizes, names,
+                                      devices=jax.devices()[:need])
             mesh_axes = tuple(reversed(names))       # innermost first
         elif mesh_axes is None:
             raise ValueError("pass mesh_axes (innermost level first) "
                              "together with an explicit mesh")
+        else:
+            mesh = mesh_mod.auto_axes(mesh)
         fn = method.executor(
             plan=plan, backend="mesh", mesh=mesh, axes=tuple(mesh_axes),
             loss=problem.loss, use_kernel=mesh_use_kernel, sync=mesh_sync)

@@ -1,43 +1,37 @@
-"""Version shims for jax API drift (the repo pins jax 0.4.37 but the code
-is written against the modern surface).
+"""Platform helpers shared by every entry point.
 
-* ``shard_map``: ``jax.shard_map`` only exists in newer jax; 0.4.37 ships it
-  as ``jax.experimental.shard_map.shard_map`` with the replication check
-  spelled ``check_rep`` instead of ``check_vma``.
-* ``make_abstract_mesh`` lives in ``repro.launch.mesh`` (the AbstractMesh
-  constructor signature changed across versions).
-* ``on_tpu``: backend probe shared by every kernel call site that flips
-  Pallas interpret mode.
+* ``on_tpu``: the one backend probe.  Kernel call sites pick Pallas
+  interpret mode from it; a backend that fails to start raises instead of
+  reading as "no TPU", so a broken chip start never runs the interpreter.
+* ``enable_compile_cache``: JAX's persistent compilation cache, placed by
+  ``JAX_COMPILATION_CACHE_DIR`` when it is set and otherwise at a fixed
+  ``.jax_cache/`` in the checkout (the path is part of the cache key, so it
+  must not move between runs).
 """
 from __future__ import annotations
 
+import os
+import pathlib
+
 import jax
 
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` where available, else the 0.4.x experimental one.
-
-    ``check_vma`` maps onto the old ``check_rep`` flag; both default to off
-    because the tree programs psum over axis subsets (per-level averaging),
-    which the replication checker cannot express."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma)
-        except TypeError:
-            # very new versions may rename/drop the flag; only swallow the
-            # mismatch when the caller wasn't relying on the check
-            if check_vma:
-                raise
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# <checkout>/src/repro/compat.py -> <checkout>/.jax_cache
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    """True when JAX's default backend is a TPU."""
+    return jax.default_backend() == "tpu"
+
+
+def enable_compile_cache() -> str:
+    """Turn on the persistent compilation cache and return its directory.
+
+    JAX reads ``JAX_COMPILATION_CACHE_DIR`` itself; only when it is unset
+    does this point the cache at ``DEFAULT_CACHE_DIR``."""
+    path = os.environ.get(CACHE_ENV)
+    if not path:
+        path = str(DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -63,6 +63,7 @@ Sync lowering (``sync=``):
 """
 from __future__ import annotations
 
+import functools
 import math
 from collections import OrderedDict
 from typing import Sequence, Tuple
@@ -70,14 +71,37 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import on_tpu, shard_map
+from repro.compat import on_tpu
 from repro.core import compression as comp_mod
 from repro.core.dual import Loss
 from repro.core.engine.plan import (
     TreePlan, full_participation, full_steps, key_plan)
 from repro.core.tree import TreeNode
+
+# the tree programs psum over axis subsets (per-level averaging), which
+# shard_map's replication check cannot express
+shard_map = functools.partial(jax.shard_map, check_vma=False)
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices=None) -> Mesh:
+    """``jax.make_mesh`` with ``Auto`` axis types.  The engine places data
+    with explicit ``NamedSharding``s and ``shard_map`` and indexes its
+    outputs numpy-style, which ``Explicit`` axes (``jax.make_mesh``'s
+    default) reject on a sharded dimension."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` itself when every axis is ``Auto``, else the same devices
+    and names with ``Auto`` axes."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * mesh.devices.ndim)
 
 Array = jax.Array
 
@@ -217,6 +241,7 @@ def get_mesh_executor(
     momentum anchors in the carry, and the server combine extrapolates
     both sides of the primal-dual pair; ``acceleration == 0`` is
     bit-identical to the plain program."""
+    mesh = auto_axes(mesh)
     _check_plan_mesh(plan, mesh, axes)
     if sync not in SYNC_MODES:
         raise ValueError(f"sync must be one of {SYNC_MODES}, got {sync!r}")
@@ -768,6 +793,7 @@ def execute_plan_mesh(
     runtime step mask (all-ones -- the static-H schedule -- by default);
     ``sync`` the collective lowering (``"psum"`` / ``"reduce_scatter"``,
     see :func:`get_mesh_executor`)."""
+    mesh = auto_axes(mesh)
     _check_plan_mesh(plan, mesh, axes)
     n, m_b = plan.n_leaves, plan.m_b
     m, d_feat = X.shape

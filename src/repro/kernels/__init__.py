@@ -13,5 +13,6 @@
 
 Each kernel ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 public wrapper) and ref.py (pure-jnp oracle); tests sweep shapes/dtypes in
-interpret mode against the oracle (this container has no TPU).
+interpret mode against the oracle, and tests/test_tpu_compile.py compiles
+each for a described v5e chip.
 """

@@ -4,7 +4,9 @@ TPU mapping (vs. the CUDA original):
   * grid = (B, H, Sq/block_q): each program owns one MXU-aligned query
     block; K/V for that (batch, kv-head) live in VMEM for the program's
     lifetime (HBM->VMEM once, not once per query block pass as on SMEM-
-    limited GPUs).
+    limited GPUs).  The kernel works on head-major (B, H, S, d) arrays so
+    every block's last two dims are (rows, d) tiles; the wrapper
+    transposes the model's (B, S, H, d) layout in and out.
   * the k-loop is a lax.fori_loop over MXU-aligned (block_k x d) slices
     with *data-dependent trip bounds*: causal masking prunes blocks above
     the diagonal, sliding windows prune blocks below `window` -- the
@@ -13,8 +15,10 @@ TPU mapping (vs. the CUDA original):
     k block; GQA is an index_map trick (q-head h reads kv-head h*KV//H),
     never a materialized repeat.
 
-VMEM budget per program: (2*Sk*d + 3*block_q*d) * bytes -- 32k context at
-d=128/bf16 is ~16 MiB, inside v5e's ~128 MiB VMEM.
+VMEM per program, double-buffered: 2 * (2*Sk*d + 2*block_q*d) * bytes.
+Seq 2048 at d=128/bf16 is ~2.2 MiB, inside v5e's 16 MiB default scoped
+VMEM; K/V blocks beyond that limit raise it explicitly (up to 100 MiB of
+v5e's 128 MiB).
 """
 from __future__ import annotations
 
@@ -24,8 +28,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.0**30
+DEFAULT_SCOPED_VMEM_BYTES = 16 * 2**20   # v5e's default scoped VMEM limit
+VMEM_LIMIT_BYTES = 100 * 2**20           # of v5e's 128 MiB per core
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, scale: float,
@@ -53,10 +60,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, scale: float,
 
     def body(j, carry):
         m_i, l_i, acc = carry
-        k_blk = jax.lax.dynamic_slice_in_dim(
-            k_ref[...], j * block_k, block_k, axis=0).astype(jnp.float32)
-        v_blk = jax.lax.dynamic_slice_in_dim(
-            v_ref[...], j * block_k, block_k, axis=0).astype(jnp.float32)
+        rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k_blk = k_ref[rows, :].astype(jnp.float32)
+        v_blk = v_ref[rows, :].astype(jnp.float32)
         s = q @ k_blk.T  # (block_q, block_k) on the MXU
         kpos = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
@@ -98,24 +104,36 @@ def flash_attention_kernel(
     assert Sq % block_q == 0 and Sk % block_k == 0
     s = scale if scale is not None else D**-0.5
 
+    itemsize = q.dtype.itemsize
+    need = 2 * itemsize * (2 * Sk * D + 2 * block_q * D)
+    if need > VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"flash attention K/V blocks (Sk={Sk}, d={D}, {q.dtype}) need "
+            f"{need} bytes of VMEM, over the kernel's {VMEM_LIMIT_BYTES}-"
+            "byte limit")
+
     grid = (B, H, Sq // block_q)
     kernel = functools.partial(
         _attn_kernel, block_k=block_k, scale=s, causal=causal,
         window=window, seq_offset=seq_offset)
+    qh, kh, vh = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, block_q, None, D),
-                         lambda b, h, i: (b, i, h, 0)),
-            pl.BlockSpec((None, Sk, None, D),
-                         lambda b, h, i, KV=KV, H=H: (b, 0, h * KV // H, 0)),
-            pl.BlockSpec((None, Sk, None, D),
-                         lambda b, h, i, KV=KV, H=H: (b, 0, h * KV // H, 0)),
+            pl.BlockSpec((None, None, block_q, D),
+                         lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((None, None, Sk, D),
+                         lambda b, h, i, KV=KV, H=H: (b, h * KV // H, 0, 0)),
+            pl.BlockSpec((None, None, Sk, D),
+                         lambda b, h, i, KV=KV, H=H: (b, h * KV // H, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((None, block_q, None, D),
-                               lambda b, h, i: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((None, None, block_q, D),
+                               lambda b, h, i: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(DEFAULT_SCOPED_VMEM_BYTES, need + 2**20)),
         interpret=interpret,
-    )(q, k, v)
+    )(qh, kh, vh)
+    return out.transpose(0, 2, 1, 3)

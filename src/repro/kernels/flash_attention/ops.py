@@ -1,8 +1,7 @@
 """Public jit'd wrapper for the flash-attention kernel.
 
-On this CPU container the kernel executes in interpret mode (the Pallas
-body runs as traced jnp on CPU); on TPU set interpret=False (the default
-flips automatically when a TPU backend is present).
+Off-TPU the kernel executes in interpret mode (the Pallas body runs as
+traced jnp); on a TPU backend it compiles with Mosaic.
 """
 from __future__ import annotations
 
@@ -11,14 +10,8 @@ from typing import Optional
 
 import jax
 
+from repro.compat import on_tpu
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
@@ -35,4 +28,4 @@ def flash_attention(q, k, v, *, causal: bool = True,
     return flash_attention_kernel(
         q, k, v, causal=causal, window=window, scale=scale,
         block_q=block_q, block_k=block_k, seq_offset=seq_offset,
-        interpret=not _on_tpu())
+        interpret=not on_tpu())

@@ -47,52 +47,54 @@ def _chunk_prefix(a: jax.Array, b: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return a, b
 
 
-def _rglru_kernel(a_ref, b_ref, h0_ref, h_ref, hlast_ref):
-    S, w = a_ref.shape
-    h = h0_ref[...]  # (w,) running state in VREGs
+def _rglru_kernel(a_ref, b_ref, h0_ref, h_ref, hlast_ref, *, chunk: int):
+    S = a_ref.shape[0]
 
-    n_chunks = S // T_CHUNK if S >= T_CHUNK else 1
-    chunk = min(T_CHUNK, S)
+    def body(c, h):                               # h: (1, w) running state
+        rows = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        A, Bc = _chunk_prefix(a_ref[rows, :].astype(jnp.float32),
+                              b_ref[rows, :].astype(jnp.float32))
+        h_chunk = A * h + Bc                      # (chunk, w)
+        h_ref[rows, :] = h_chunk.astype(h_ref.dtype)
+        return h_chunk[chunk - 1:]
 
-    def body(c, h):
-        a_c = jax.lax.dynamic_slice_in_dim(a_ref[...], c * chunk, chunk, 0)
-        b_c = jax.lax.dynamic_slice_in_dim(b_ref[...], c * chunk, chunk, 0)
-        A, Bc = _chunk_prefix(a_c.astype(jnp.float32),
-                              b_c.astype(jnp.float32))
-        h_chunk = A * h[None, :] + Bc  # (chunk, w)
-        pl.store(h_ref, (pl.ds(c * chunk, chunk), slice(None)),
-                 h_chunk.astype(h_ref.dtype))
-        return h_chunk[-1]
-
-    h = jax.lax.fori_loop(0, n_chunks, body, h)
+    h = jax.lax.fori_loop(0, S // chunk, body,
+                          h0_ref[...].astype(jnp.float32))
     hlast_ref[...] = h.astype(hlast_ref.dtype)
 
 
 def rglru_scan_kernel(a: jax.Array, b: jax.Array, h0: jax.Array,
                       block_w: int = 128, interpret: bool = True
                       ) -> Tuple[jax.Array, jax.Array]:
-    """a, b: (B, S, W) f32; h0: (B, W). Returns (h (B,S,W), h_last (B,W))."""
+    """a, b: (B, S, W) f32; h0: (B, W). Returns (h (B,S,W), h_last (B,W)).
+
+    S must be a multiple of ``T_CHUNK`` (or shorter than it)."""
     B, S, W = a.shape
     block_w = min(block_w, W)
-    assert W % block_w == 0, (W, block_w)
+    chunk = min(T_CHUNK, S)
+    if W % block_w or S % chunk:
+        raise ValueError(f"rglru kernel needs W % block_w == 0 and "
+                         f"S % {chunk} == 0; got W={W}, block_w={block_w}, "
+                         f"S={S}")
     grid = (B, W // block_w)
-
+    # h0 / h_last ride as (B, 1, W) so their blocks keep a full-size
+    # second-minor dim (TPU block tiling)
     h, hlast = pl.pallas_call(
-        _rglru_kernel,
+        functools.partial(_rglru_kernel, chunk=chunk),
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, S, block_w), lambda i, j: (i, 0, j)),
             pl.BlockSpec((None, S, block_w), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((None, block_w), lambda i, j: (i, j)),
+            pl.BlockSpec((None, 1, block_w), lambda i, j: (i, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((None, S, block_w), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((None, block_w), lambda i, j: (i, j)),
+            pl.BlockSpec((None, 1, block_w), lambda i, j: (i, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, S, W), a.dtype),
-            jax.ShapeDtypeStruct((B, W), a.dtype),
+            jax.ShapeDtypeStruct((B, 1, W), a.dtype),
         ],
         interpret=interpret,
-    )(a, b, h0)
-    return h, hlast
+    )(a, b, h0.reshape(B, 1, W))
+    return h, hlast.reshape(B, W)

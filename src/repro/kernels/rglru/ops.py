@@ -6,14 +6,8 @@ from typing import Tuple
 
 import jax
 
+from repro.compat import on_tpu
 from repro.kernels.rglru.kernel import rglru_scan_kernel
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
 
 
 @functools.partial(jax.jit, static_argnames=("block_w",))
@@ -22,4 +16,4 @@ def rglru_scan(a, b, h0, *, block_w: int = 128
     """Diagonal linear recurrence h_t = a_t h_{t-1} + b_t over (B, S, W).
     Returns (all states, final state)."""
     return rglru_scan_kernel(a, b, h0, block_w=block_w,
-                             interpret=not _on_tpu())
+                             interpret=not on_tpu())

@@ -8,7 +8,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import on_tpu as _on_tpu
+from repro.compat import on_tpu
 from repro.core.dual import Loss
 from repro.kernels.sdca.kernel import sdca_block_kernel
 
@@ -34,7 +34,7 @@ def sdca_block_solve(
     lm = lam * m_total
     idx = jax.random.randint(key, (K, num_steps), 0, m_b)
     da, dw = sdca_block_kernel(X, y, alpha, w, idx, loss=loss, lm=lm,
-                               interpret=not _on_tpu())
+                               interpret=not on_tpu())
     new_alpha = alpha + da / K
     new_w = w + jnp.sum(dw, axis=0) / K
     return new_alpha, new_w, dw

@@ -9,12 +9,16 @@ pods (512 chips) joined over the slow DCI/network hop. Axes:
 ``make_production_mesh`` is a function (never a module constant) so importing
 this module never touches jax device state; the dry-run sets
 ``--xla_force_host_platform_device_count=512`` before any jax import.
+
+Every mesh here has ``Auto`` axes (``repro.core.engine.mesh.make_mesh``).
 """
 from __future__ import annotations
 
 from typing import Tuple
 
 import jax
+
+from repro.core.engine.mesh import make_mesh
 
 SINGLE_POD_SHAPE: Tuple[int, ...] = (16, 16)
 SINGLE_POD_AXES: Tuple[str, ...] = ("data", "model")
@@ -25,28 +29,21 @@ MULTI_POD_AXES: Tuple[str, ...] = ("pod", "data", "model")
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = MULTI_POD_SHAPE if multi_pod else SINGLE_POD_SHAPE
     axes = MULTI_POD_AXES if multi_pod else SINGLE_POD_AXES
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_abstract_mesh(shape: Tuple[int, ...],
                        axes: Tuple[str, ...]) -> "jax.sharding.AbstractMesh":
-    """Version-portable ``AbstractMesh`` construction.
-
-    Newer jax takes ``AbstractMesh(axis_sizes, axis_names)``; jax 0.4.37
-    takes a single ``shape_tuple`` of ``(name, size)`` pairs.  Accepts the
-    modern ``(shape, axes)`` calling convention either way."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape, strict=True)))
+    """``AbstractMesh`` over ``(shape, axes)`` (``Auto`` axes)."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over whatever devices exist (tests / smoke runs)."""
     n = len(jax.devices())
-    assert n % model == 0
-    return jax.make_mesh((n // model, model), SINGLE_POD_AXES)
+    if n % model:
+        raise ValueError(f"{n} devices do not split into model={model}")
+    return make_mesh((n // model, model), SINGLE_POD_AXES)
 
 
 def data_axes(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:

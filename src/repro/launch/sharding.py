@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.core.engine.mesh import auto_axes
 from repro.launch.mesh import axis_size, data_axes
 
 PyTree = Any
@@ -208,6 +209,7 @@ def param_specs(cfg: ModelConfig, params_shape: PyTree, mesh: Mesh,
 
 
 def to_named(spec_tree: PyTree, mesh: Mesh) -> PyTree:
+    mesh = auto_axes(mesh)
     return jax.tree.map(
         lambda s: NamedSharding(mesh, s), spec_tree,
         is_leaf=lambda x: isinstance(x, P))

@@ -19,6 +19,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.configs.shapes import ShapeSpec
+from repro.core.engine.mesh import auto_axes
 from repro.launch import sharding as sh
 from repro.launch.mesh import data_axes
 from repro.models import transformer
@@ -178,6 +179,7 @@ def build_cell(cfg: ModelConfig, shape: ShapeSpec, mesh: Mesh,
                optimizer: Optional[Optimizer] = None,
                microbatches: int = 1) -> CellProgram:
     """Construct the jitted step + shardings + abstract inputs for a cell."""
+    mesh = auto_axes(mesh)
     pshape = params_shape(cfg)
     pspecs = sh.param_specs(cfg, pshape, mesh, rules)
     psh = sh.to_named(pspecs, mesh)

@@ -18,6 +18,7 @@ import warnings
 from typing import Any, Dict, Optional, Sequence
 
 from repro.api import CheckpointPolicy, Problem, Session, Topology
+from repro.compat import enable_compile_cache
 from repro.configs.registry import ARCHS
 from repro.launch.mesh import make_host_mesh
 from repro.optim import get_optimizer
@@ -99,6 +100,7 @@ def train(cfg, *, steps: int, batch: int, seq: int, mesh=None,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(ARCHS))
     ap.add_argument("--smoke", action="store_true",

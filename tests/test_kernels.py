@@ -1,5 +1,5 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode
-(deliverable c; no TPU in this container)."""
+(deliverable c; the v5e compiles are in test_tpu_compile.py)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -185,3 +185,45 @@ def test_sdca_solve_increases_dual_and_converges():
     w_check = dual_mod.w_of_alpha(alpha.reshape(-1), X, lam)
     np.testing.assert_allclose(np.asarray(w), np.asarray(w_check),
                                rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("per_leaf_w,masked", [(True, True), (False, False)])
+@pytest.mark.parametrize("K,m_b,d,H", [(3, 40, 16, 96), (2, 64, 2000, 100)])
+def test_sdca_kernel_bit_identical_to_ref(K, m_b, d, H, per_leaf_w, masked):
+    """Interpret mode: the kernel's iterates equal the oracle's bit for bit
+    (smoke width d = 2,000 included); only WHERE they live differs."""
+    loss = dual_mod.LOSSES["smooth_hinge_1"]
+    kx, ky, ka, kw, ki, km = jax.random.split(jax.random.PRNGKey(4), 6)
+    X = jax.random.normal(kx, (K, m_b, d))
+    y = jnp.sign(jax.random.normal(ky, (K, m_b)))
+    alpha = 0.5 * jnp.abs(0.1 * jax.random.normal(ka, (K, m_b))) * y
+    w = 0.1 * jax.random.normal(kw, (K, d) if per_leaf_w else (d,))
+    idx = jax.random.randint(ki, (K, H), 0, m_b)
+    mask = (jax.random.uniform(km, (K, H)) > 0.3).astype(jnp.float32) \
+        if masked else None
+    lm = jnp.float32(0.01 * K * m_b)
+    da_k, dw_k = jax.jit(lambda *a: sdca_block_kernel(
+        *a, loss=loss, lm=lm, step_mask=mask, interpret=True))(
+        X, y, alpha, w, idx)
+    da_r, dw_r = jax.jit(lambda *a: sdca_block_ref(
+        *a, loss=loss, lm=lm, step_mask=mask))(X, y, alpha, w, idx)
+    np.testing.assert_array_equal(np.asarray(da_k), np.asarray(da_r))
+    np.testing.assert_array_equal(np.asarray(dw_k), np.asarray(dw_r))
+
+
+def test_sdca_kernel_refuses_block_over_vmem_limit():
+    """A leaf block that cannot fit VMEM raises, naming its bytes -- it is
+    never routed to the reference."""
+    from repro.kernels.sdca.kernel import VMEM_LIMIT_BYTES, kernel_bytes
+    K, m_b, d, H = 1, 8192, 4096, 16
+    need, _ = kernel_bytes(m_b, d, H)
+    assert need > VMEM_LIMIT_BYTES
+    shapes = (jax.ShapeDtypeStruct((K, m_b, d), jnp.float32),
+              jax.ShapeDtypeStruct((K, m_b), jnp.float32),
+              jax.ShapeDtypeStruct((K, m_b), jnp.float32),
+              jax.ShapeDtypeStruct((d,), jnp.float32),
+              jax.ShapeDtypeStruct((K, H), jnp.int32))
+    with pytest.raises(ValueError, match=str(need)):
+        jax.eval_shape(lambda *a: sdca_block_kernel(
+            *a, loss=dual_mod.LOSSES["squared"], lm=1.0, interpret=True),
+            *shapes)

@@ -1,0 +1,86 @@
+"""Ahead-of-time v5e compiles of the Pallas kernels at real widths.
+
+The TPU compiler is installed even where no chip is attached: a described
+``v5e:2x2`` topology lets Mosaic accept or refuse each kernel exactly as
+the chip's compiler would (block tiling, SMEM/VMEM limits, unsupported
+primitives).  Nothing runs; each test asserts the compiled program holds
+the kernel (``tpu_custom_call``).  The topology is described inside a
+fixture, never at import, because only one process may load the TPU
+library at a time.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import dual as dual_mod
+from repro.kernels.flash_attention.kernel import flash_attention_kernel
+from repro.kernels.rglru.kernel import rglru_scan_kernel
+from repro.kernels.sdca.kernel import sdca_block_kernel
+
+
+@pytest.fixture(scope="module")
+def topo():
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        from jax.experimental import topologies
+        try:
+            yield topologies.get_topology_desc(platform="tpu",
+                                               topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler in this environment
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    sh = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+    return make
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("per_leaf_w,masked", [(True, True), (False, False)])
+def test_sdca_kernel_compiles_at_smoke_leaf_block(spec, per_leaf_w, masked):
+    """chip_smoke's leaf blocks: 512 leaves of 784 x 2,000 f32, H = 784."""
+    K, m_b, d, H = 512, 784, 2000, 784
+    loss = dual_mod.LOSSES["smooth_hinge_1"]
+
+    def f(X, y, a, w, idx, mk, lm):
+        return sdca_block_kernel(X, y, a, w, idx, loss=loss, lm=lm,
+                                 step_mask=mk if masked else None,
+                                 interpret=False)
+
+    text = _compiled_text(
+        f, spec((K, m_b, d)), spec((K, m_b)), spec((K, m_b)),
+        spec((K, d) if per_leaf_w else (d,)), spec((K, H), jnp.int32),
+        spec((K, H)), spec(()))
+    assert "tpu_custom_call" in text
+
+
+def test_flash_attention_compiles_at_danube_heads(spec):
+    """h2o-danube-1.8b heads: 32 query / 8 kv heads of 128, seq 2048,
+    window 4096, bf16."""
+    def f(q, k, v):
+        return flash_attention_kernel(q, k, v, causal=True, window=4096,
+                                      interpret=False)
+
+    text = _compiled_text(f, spec((1, 2048, 32, 128), jnp.bfloat16),
+                          spec((1, 2048, 8, 128), jnp.bfloat16),
+                          spec((1, 2048, 8, 128), jnp.bfloat16))
+    assert "tpu_custom_call" in text
+
+
+def test_rglru_compiles_at_width_2560(spec):
+    def f(a, b, h0):
+        return rglru_scan_kernel(a, b, h0, interpret=False)
+
+    text = _compiled_text(f, spec((1, 2048, 2560)), spec((1, 2048, 2560)),
+                          spec((1, 2560)))
+    assert "tpu_custom_call" in text

@@ -86,3 +86,51 @@ def test_kernel_vs_ref_leaf_same_result(data):
                                rtol=1e-6, atol=1e-7)
     np.testing.assert_allclose(np.asarray(w1), np.asarray(w2),
                                rtol=1e-6, atol=1e-7)
+
+
+_MULTI_DEVICE_CHILD = """
+import jax, numpy as np
+from repro.api import Problem, Session, Topology
+from repro.data.synthetic import gaussian_classification
+assert len(jax.devices()) == 4, jax.devices()
+X, y = gaussian_classification(m=512, d=96, key=jax.random.PRNGKey(0))
+prob = Problem.svm(X, y, lam=0.05)
+topo = Topology.two_level(2, 2, 128, local_steps=64)
+key = jax.random.PRNGKey(1)
+ref = Session.compile(prob, topo, backend="vmap").run(4, key=key)
+res = Session.compile(prob, topo, backend="mesh", mesh_use_kernel=False,
+                      mesh_sync={sync!r}).run(4, key=key)
+np.savez({out!r}, alpha=np.asarray(res.alpha), w=np.asarray(res.w),
+         ref_alpha=np.asarray(ref.alpha), ref_w=np.asarray(ref.w))
+"""
+
+
+@pytest.mark.parametrize("sync", ["psum", "reduce_scatter"])
+def test_mesh_backend_across_four_devices_matches_vmap(sync, tmp_path):
+    """The multi-device mesh path (the four-chip layout: tree level 1
+    across devices) on 4 virtual CPU devices in a child process: default
+    ``jax.make_mesh`` axes are Explicit in JAX 0.9, so the engine must
+    build Auto meshes for its sharded outputs to index.  psum is
+    bit-identical to the host backend; reduce_scatter agrees to f32
+    rounding."""
+    import os
+    import subprocess
+    import sys
+    out = tmp_path / "out.npz"
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         _MULTI_DEVICE_CHILD.format(sync=sync, out=str(out))],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    with np.load(out) as z:
+        if sync == "psum":
+            np.testing.assert_array_equal(z["alpha"], z["ref_alpha"])
+            np.testing.assert_array_equal(z["w"], z["ref_w"])
+        else:
+            np.testing.assert_allclose(z["alpha"], z["ref_alpha"],
+                                       rtol=1e-5, atol=1e-6)
+            np.testing.assert_allclose(z["w"], z["ref_w"],
+                                       rtol=1e-5, atol=1e-6)
