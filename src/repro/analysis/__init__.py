@@ -16,9 +16,9 @@ Three layers (see ``docs/analysis.md``):
     chunk loop's dispatch region are disallowed, and an opt-in NaN/Inf
     sanitizer checks the chunk carry each round.
   * :mod:`repro.analysis.rules` -- repo-specific AST lint rules run by
-    ``python -m repro.analysis``: no wall-clock / Python RNG inside
-    traced bodies, no static closure capture of runtime operands
-    (lambda / lr / local_h / periods), no ``jax.jit`` outside
+    ``python -m repro.analysis``: no wall-clock / Python RNG / program
+    spans inside traced bodies, no static closure capture of runtime
+    operands (lambda / lr / local_h / periods), no ``jax.jit`` outside
     ``core/engine`` + ``kernels`` without a waiver, no mutable defaults
     in frozen dataclasses.
 """

@@ -18,6 +18,11 @@ Rule catalog (``docs/analysis.md`` has the rationale in full):
     ``participation``) must reach a traced body as ARGUMENTS, never as
     closure captures: a captured Python float is a compile-time
     constant, so every sweep point retraces (the PR-4 lambda bug class).
+``span-in-trace``
+    No program span (``repro.core.instrument.span`` or
+    ``jax.profiler.TraceAnnotation``) inside a traced body: it opens and
+    closes once, at trace time, so it would time the tracing, not the
+    running (name device stages with ``jax.named_scope`` there instead).
 ``jit-outside-engine``
     ``jax.jit`` belongs in ``core/engine`` and ``kernels`` (plus
     explicitly waived call sites): stray jits fragment the executor
@@ -68,6 +73,9 @@ WALLCLOCK_CALLS = {
     ("time", "time"), ("time", "perf_counter"), ("time", "monotonic"),
     ("time", "process_time"), ("datetime", "now"), ("datetime", "utcnow"),
 }
+# host spans: ``instrument.span`` and the profiler annotations it wraps
+SPAN_CALLS = {"span", "instrument.span"}
+SPAN_ANNOTATIONS = {"TraceAnnotation", "StepTraceAnnotation"}
 PYRANDOM_MODULES = {"random"}
 NUMPY_RANDOM_ATTR = "random"   # np.random.* inside a traced body
 # runtime operands of the schedule engine: these names reaching a traced
@@ -304,6 +312,14 @@ class _Analyzer(ast.NodeVisitor):
                     f"{fn}() inside a traced body evaluates ONCE at "
                     "trace time (the compiled program reuses the baked "
                     "constant); time around the dispatch instead",
+                    owner)
+            if fn in SPAN_CALLS or parts[-1] in SPAN_ANNOTATIONS:
+                self._emit(
+                    "span-in-trace", node,
+                    f"{fn}() inside a traced body opens and closes once, "
+                    "at trace time, so it times the tracing, not the "
+                    "running; span the dispatch on the host, or name the "
+                    "device stage with jax.named_scope",
                     owner)
             if parts[0] in PYRANDOM_MODULES or \
                     (len(parts) >= 2 and parts[0] in ("np", "numpy")

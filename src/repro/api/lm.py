@@ -44,6 +44,7 @@ from repro.core.engine import lm as lm_mod
 from repro.core.engine import mesh as mesh_mod
 from repro.core.engine import plan as plan_mod
 from repro.core.engine.method import get_method
+from repro.core.instrument import span
 from repro.data.lm import lm_batch
 
 PyTree = Any
@@ -349,20 +350,26 @@ class LMSession:
                 if adaptive:
                     extra["h"] = periods[0]
             for _ in range(n_this):
-                t0 = time.time()
-                with _retrace_ctx():
-                    state, metrics = exec_fn(state, self._batch_at(i),
-                                             periods_arr, part, lr_arr)
-                i += 1
-                done += 1
-                if record_history:
-                    entry = {"step": i, "loss": float(metrics["loss"]),
-                             "sec": time.time() - t0}
-                    if extra:
-                        entry.update(extra)
-                    history.append(entry)
-                    if on_step is not None:
-                        on_step(entry)
+                with span("LMSession.step", step=i + 1):
+                    t0 = time.time()
+                    with span("LMSession.batch", step=i + 1):
+                        batch = self._batch_at(i)
+                    with _retrace_ctx(), \
+                            span("LMSession.dispatch", step=i + 1):
+                        state, metrics = exec_fn(state, batch, periods_arr,
+                                                 part, lr_arr)
+                    i += 1
+                    done += 1
+                    if record_history:
+                        with span("LMSession.loss_read", step=i):
+                            loss = float(metrics["loss"])
+                        entry = {"step": i, "loss": loss,
+                                 "sec": time.time() - t0}
+                        if extra:
+                            entry.update(extra)
+                        history.append(entry)
+                if record_history and on_step is not None:
+                    on_step(entry)
             if guard is not None and guard.sanitize:
                 guard.check_carry(state, f"state@step{i}")
             # eq.-(12) replanning feeds the NEXT round through the runtime

@@ -44,7 +44,7 @@ from repro.core.engine import host as host_mod
 from repro.core.engine import mesh as mesh_mod
 from repro.core.engine import plan as plan_mod
 from repro.core.engine.method import get_method
-from repro.core.instrument import SolveResult, record_round
+from repro.core.instrument import SolveResult, record_round, span
 from repro.api.problem import Problem
 from repro.api.schedule import (
     ResolvedSchedule, Schedule, leaf_h_spec, runtime_tree)
@@ -378,6 +378,23 @@ class Session:
         T = self.resolved.rounds if rounds is None else int(rounds)
         if T < 0:
             raise ValueError(f"rounds must be >= 0, got {T}")
+        with span("Session.run", rounds=T, backend=self.backend) as sp:
+            return self._run(
+                sp, T, key=key, warm_start=warm_start,
+                record_history=record_history, history_every=history_every,
+                on_round=on_round, straggler=straggler, lam=lam,
+                local_h=local_h, acceleration=acceleration,
+                checkpoint=checkpoint, _ef_state=_ef_state,
+                _history_prefix=_history_prefix,
+                _defer_history=_defer_history, _final_save=_final_save)
+
+    def _run(self, sp, T, *, key, warm_start, record_history, history_every,
+             on_round, straggler, lam, local_h, acceleration, checkpoint,
+             _ef_state, _history_prefix, _defer_history, _final_save
+             ) -> SolveResult:
+        """:meth:`run`'s body, inside its ``Session.run`` span ``sp``: the
+        stages below get spans of their own, and ``sp`` gets the call's
+        first round, recorded objective calls and host-to-device bytes."""
         every = int(history_every)
         if every < 1:
             raise ValueError(f"history_every must be >= 1, got {every}")
@@ -385,7 +402,6 @@ class Session:
         loss = self.problem.loss
         lam = self.problem.lam if lam is None else float(lam)
         m = self.problem.m
-        lm_in = host_mod.regularizer_scale(lam, m, X.dtype)
 
         accelerated = self.acceleration is not None
         if acceleration is not None and not accelerated:
@@ -412,12 +428,9 @@ class Session:
                 "per-depth momentum anchors are part of the chunk carry "
                 "but not of the flat (alpha, w, residuals) snapshot "
                 "payload, so a resumed run would diverge")
-        # the momentum coefficient is a RUNTIME operand of the sdca_acc
-        # executors: converted once here, never part of a cache key
-        acc_args = (jnp.asarray(float(acc_run), X.dtype),) \
-            if accelerated else ()
 
-        alpha, w, k = self._start_state(warm_start, key, lam)
+        with span("Session.start_state"):
+            alpha, w, k = self._start_state(warm_start, key, lam)
         K_root = len(self.resolved.chunk_tree.children)
         chunk_tree, plan = self.resolved.chunk_tree, self.plan
         h_run = local_h if local_h is not None else self.resolved.runtime_h
@@ -431,6 +444,7 @@ class Session:
             t0_round = int(warm_start.history[-1]["round"])
             t0_time = float(warm_start.history[-1]["time"])
             record_initial = False
+        sp.set_metadata(first_round=t0_round + 1)
 
         ckpt_mgr, ck_every, k_cur = None, 0, k
         ckpt_pending, k_lag = None, 0
@@ -503,13 +517,6 @@ class Session:
                     get_method(method_name).executor(
                         plan=plan, backend=self.backend,
                         loss=self.problem.loss, record_history=False)
-        if mesh:
-            a_carry = jnp.asarray(alpha, X.dtype).reshape(
-                plan.n_leaves, plan.m_b)
-        else:
-            a_carry = jnp.asarray(alpha, X.dtype)
-        w = jnp.asarray(w, X.dtype)
-
         history: list = []
         clock = {"async": t0_time, "sync": t0_time}
 
@@ -534,15 +541,6 @@ class Session:
                 materialize_history(history)     # streaming needs host values
                 on_round(history[-1])
 
-        # the all-ones mask is loop-invariant: convert (and, on mesh,
-        # device_put) it once instead of per round
-        if mesh:
-            part_ones = jax.device_put(
-                jnp.asarray(plan_mod.full_participation(plan), X.dtype).T,
-                self._spec_sharding)
-        else:
-            part_ones = jnp.asarray(plan_mod.full_participation(plan))
-
         # the runtime schedule: a step mask per chunk.  Loop-invariant
         # unless an adaptive straggler policy replans H mid-run -- then
         # only this INPUT array changes, never the compiled program.
@@ -562,21 +560,47 @@ class Session:
                 return plan.leaf_h.astype(np.int64)
             return np.minimum(leaf_h_spec(h, plan.n_leaves), plan.leaf_h)
 
-        steps_now = steps_dev(h_run)
+        with span("Session.operands"):
+            lm_in = host_mod.regularizer_scale(lam, m, X.dtype)
+            # the momentum coefficient is a RUNTIME operand of the sdca_acc
+            # executors: converted once here, never part of a cache key
+            acc_args = (jnp.asarray(float(acc_run), X.dtype),) \
+                if accelerated else ()
+            if mesh:
+                a_carry = jnp.asarray(alpha, X.dtype).reshape(
+                    plan.n_leaves, plan.m_b)
+            else:
+                a_carry = jnp.asarray(alpha, X.dtype)
+            w = jnp.asarray(w, X.dtype)
+            # the all-ones mask is loop-invariant: convert (and, on mesh,
+            # device_put) it once instead of per round
+            if mesh:
+                part_ones = jax.device_put(
+                    jnp.asarray(plan_mod.full_participation(plan),
+                                X.dtype).T, self._spec_sharding)
+            else:
+                part_ones = jnp.asarray(plan_mod.full_participation(plan))
+            steps_now = steps_dev(h_run)
+            state = None
+            if state_exec is not None:
+                state = state_exec.init(X, a_carry, w)
+                if _ef_state:
+                    # restore path: substitute the checkpointed
+                    # error-feedback residuals (the one piece of the
+                    # blocked carry that does not collapse into (alpha, w)
+                    # at a root-round boundary)
+                    from repro.runtime import fault as fault_mod
+                    state = fault_mod.with_ef_residuals(self, state,
+                                                        _ef_state)
+        # host-to-device bytes of this call's converted operands (shapes
+        # only: nothing here reads an array)
+        upload = (lm_in.nbytes + sum(a.nbytes for a in acc_args)
+                  + part_ones.nbytes + steps_now.nbytes)
         h_eff_now = h_effective(h_run)
         h_now = int(h_eff_now.max())
         adaptive = straggler is not None and \
             getattr(straggler, "adaptive", None) is not None
         next_h = None
-        state = None
-        if state_exec is not None:
-            state = state_exec.init(X, a_carry, w)
-            if _ef_state:
-                # restore path: substitute the checkpointed error-feedback
-                # residuals (the one piece of the blocked carry that does
-                # not collapse into (alpha, w) at a root-round boundary)
-                from repro.runtime import fault as fault_mod
-                state = fault_mod.with_ef_residuals(self, state, _ef_state)
 
         # strict mode: by loop entry every executor is cached (compile
         # built them, the revalidation above proved it), so each chunk
@@ -595,10 +619,13 @@ class Session:
 
         # all rounds' keys in one walk of the equivalent monolithic tree
         # (the legacy chain), so the chunk loop does no host RNG work
-        keys_all = plan_mod.chunked_key_plan(chunk_tree, plan, k, T)
+        with span("Session.key_plan"):
+            keys_all = plan_mod.chunked_key_plan(chunk_tree, plan, k, T)
         if record_initial:
-            record(0, a_carry.reshape(m) if mesh else a_carry)
+            with span("Session.record", round=t0_round):
+                record(0, a_carry.reshape(m) if mesh else a_carry)
         for t in range(1, T + 1):
+            r_no = t0_round + t
             keys = keys_all[t - 1]
             extra = None
             prt = part_ones
@@ -613,6 +640,7 @@ class Session:
                     h_eff_now = eff_next
                     h_now = int(eff_next.max())
                     steps_now = steps_dev(next_h)
+                    upload += steps_now.nbytes
                     straggler.retime(tree_mod.strip_delays(
                         runtime_tree(chunk_tree, next_h)).solve_time())
                 next_h = None
@@ -624,6 +652,7 @@ class Session:
                 prt = jax.device_put(
                     jnp.asarray(part, X.dtype).T, self._spec_sharding) \
                     if mesh else jnp.asarray(part)
+                upload += prt.nbytes
                 clock["async"] += step.dt_async
                 clock["sync"] += step.dt_sync
                 extra = {"time_sync": clock["sync"],
@@ -633,42 +662,41 @@ class Session:
                     if step.h_suggest is not None:
                         next_h = int(min(max(step.h_suggest, 1),
                                          plan.h_max))
-            if mesh:
+            # operand conversion stays OUTSIDE the guarded region: inside
+            # it every implicit host transfer is an error
+            with span("Session.key_upload", round=r_no):
                 kys = jax.device_put(
                     jnp.asarray(keys.transpose(1, 0, 2)),
-                    self._spec_sharding)
-                if state_exec is None:
-                    with _dispatch_ctx(t):
+                    self._spec_sharding) if mesh else jnp.asarray(keys)
+            upload += keys.nbytes
+            with _dispatch_ctx(t):
+                with span("Session.dispatch", round=r_no):
+                    if mesh and state_exec is None:
                         a_carry, wrows = self._fn(self._Xs, self._ys,
                                                   a_carry, w, kys, prt,
                                                   steps_now, lm_in)
                         w = wrows[0]
-                        if rec_now:
-                            record(t, a_carry.reshape(m), extra)
-                else:
-                    with _dispatch_ctx(t):
+                    elif mesh:
                         state = state_exec.step(self._Xs, self._ys, state,
                                                 kys, prt, steps_now, lm_in,
                                                 *acc_args)
-                        if rec_now:
-                            record(t, state[0].reshape(m), extra)
-            elif state_exec is None:
-                # operand conversion stays OUTSIDE the guarded region:
-                # inside it every implicit host transfer is an error
-                kys = jnp.asarray(keys)
-                with _dispatch_ctx(t):
-                    a_carry, w = self._fn(X, y, kys, a_carry, w,
-                                          prt, steps_now, lm_in)
-                    if rec_now:
-                        record(t, a_carry, extra)
-            else:
-                kys = jnp.asarray(keys)
-                with _dispatch_ctx(t):
-                    state = state_exec.step(X, y, kys, state,
-                                            prt, steps_now, lm_in,
-                                            *acc_args)
-                    if rec_now:
-                        record(t, state_exec.finalize(state)[0], extra)
+                    elif state_exec is None:
+                        a_carry, w = self._fn(X, y, kys, a_carry, w,
+                                              prt, steps_now, lm_in)
+                    else:
+                        state = state_exec.step(X, y, kys, state,
+                                                prt, steps_now, lm_in,
+                                                *acc_args)
+                if rec_now:
+                    with span("Session.record", round=r_no):
+                        if state_exec is None:
+                            a_rec = a_carry
+                        elif mesh:
+                            a_rec = state[0]
+                        else:
+                            a_rec = state_exec.finalize(state)[0]
+                        record(t, a_rec.reshape(m) if mesh else a_rec,
+                               extra)
             if guard is not None and guard.sanitize:
                 guard.check_carry(
                     state if state_exec is not None else (a_carry, w),
@@ -721,7 +749,8 @@ class Session:
                     if ckpt_pending is not None:
                         ckpt_mgr.save(*ckpt_pending)
                     ckpt_pending = (t0_round + t, payload, meta)
-        k = plan_mod.advance_root_key(k, T, K_root)
+        with span("Session.advance_key"):
+            k = plan_mod.advance_root_key(k, T, K_root)
         if ckpt_mgr is not None:
             if ckpt_pending is not None:
                 ckpt_mgr.save(*ckpt_pending)
@@ -734,7 +763,9 @@ class Session:
         else:
             alpha_out = a_carry.reshape(m) if mesh else a_carry
         if not _defer_history:
-            materialize_history(history)
+            with span("Session.materialize"):
+                materialize_history(history)
+        sp.set_metadata(recorded=len(history), upload_bytes=upload)
         return SolveResult(alpha=alpha_out, w=w, history=history,
                            next_key=k, lam=lam)
 

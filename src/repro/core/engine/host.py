@@ -313,8 +313,9 @@ def _build_host_executor(plan: TreePlan, *, loss, record_history,
             acceleration = jnp.asarray(acceleration, dtype)
         lam = lm / m                     # only the in-program objective
         vmask = valid_f.astype(dtype)
-        Xb = X[gather_idx] * vmask[:, :, None]                # (n, m_b, d)
-        yb = y[gather_idx] * vmask                            # (n, m_b)
+        with jax.named_scope("reblock"):
+            Xb = X[gather_idx] * vmask[:, :, None]            # (n, m_b, d)
+            yb = y[gather_idx] * vmask                        # (n, m_b)
 
         def draw_idx(keys_s):
             """The tick's (n, h_max) coordinate draws, exactly as the legacy
@@ -340,6 +341,7 @@ def _build_host_executor(plan: TreePlan, *, loss, record_history,
             return sdca_block_ref(Xb, yb, a, w, idx_s, loss=loss, lm=lm,
                                   step_mask=mk)
 
+        @jax.named_scope("objective")
         def objective(a, w):
             """(dual, primal) at a root sync, where w rows are all equal."""
             w0 = w[0]
@@ -349,6 +351,7 @@ def _build_host_executor(plan: TreePlan, *, loss, record_history,
             pv = reg + jnp.sum(vmask * loss.value(margins, yb)) / m
             return dv, pv
 
+        @jax.named_scope("codec")
         def roundtrip(dd, target):
             """The receiver's view of this depth's per-edge messages: each
             compressed leaf row goes through its edge's (quantize +
@@ -375,119 +378,126 @@ def _build_host_executor(plan: TreePlan, *, loss, record_history,
                 (srvP, srvA), rest = rest[:2], rest[2:]
             res = rest[0] if has_comp else ()
             keys_s, smask, sync_s, ref_s, hflag, part_s, steps_s = xs
-            da, dw = leaf_batch(a, w, keys_s, smask, steps_s)
-            a = a + da
-            w = w + dw
-            # syncs bottom-up; a leaf with part_s == 0 is absent from every
-            # event of this tick.  `srvW[dd]` is the group's server state;
-            # it advances (and later rebases) GROUP-wide so an absent
-            # leaf's copy stays coherent with its group's.
-            act_of: list = [None] * D
-            for dd in range(D - 1, -1, -1):
-                ev = sync_s[dd]                               # (n,) event
-                e = ev * part_s                               # participants
-                wc = wcoef[dd].astype(dtype)
-                absent_g = jax.ops.segment_sum(
-                    (ev - e) * wc, gids[dd], num_segments=ngroups[dd])
-                present_g = jax.ops.segment_sum(
-                    e * wc, gids[dd], num_segments=ngroups[dd])
-                # exact 1.0 under full participation => x/denom is x/1.0,
-                # bit-identical to the synchronous path
-                denom_g = jnp.where(
-                    absent_g == 0, jnp.ones((), dtype),
-                    jnp.where(present_g > 0, present_g, jnp.ones((), dtype)))
-                denom = denom_g[gids[dd]]                     # (n,)
-                act = (ev > 0) & (present_g > 0)[gids[dd]]    # group live
-                eb = (e > 0)[:, None]                         # leaf attends
-                base_a = (snapA[dd]
-                          + (ascale[dd] / denom)[:, None] * (a - snapA[dd]))
-                if accelerated:
-                    # extrapolate alpha along its own combined sequence with
-                    # the SAME coefficient as the server w below: w is the
-                    # linear image X^T alpha / (lambda m) of alpha, so a
-                    # shared extrapolation keeps the primal-dual pair
-                    # consistent (momentum on w alone would decouple them)
-                    ext_a = base_a + acceleration * (base_a - srvA[dd])
-                    new_a = jnp.where(acceleration != 0, ext_a, base_a)
-                    srvA = srvA.at[dd].set(jnp.where(eb, base_a, srvA[dd]))
-                    a = jnp.where(eb, new_a, a)
-                else:
-                    a = jnp.where(eb, base_a, a)
-                # a partially-present child is represented by its surviving
-                # leaves (all carrying the child's full delta), so their
-                # per-leaf coefficients scale up by |child| / |present|;
-                # fully-present children multiply by exactly 1.0
-                cnt_c = jax.ops.segment_sum(e, cids[dd],
-                                            num_segments=nchildren[dd])
-                corr = (csize[dd]
-                        / jnp.maximum(cnt_c, 1.0)[cids[dd]]).astype(dtype)
-                delta_w = w - snapW[dd]
-                if dd in comp_idx:
-                    # error feedback: compress(delta + residual); the
-                    # residual advances only for leaves that actually
-                    # deliver at this event (e > 0)
-                    ri = comp_idx[dd]
-                    r_prev = res[ri]
-                    target = delta_w.astype(jnp.float32) + r_prev
-                    approx = roundtrip(dd, target)
-                    e_col = (e > 0)[:, None]
-                    res = (res[:ri]
-                           + (jnp.where(e_col, target - approx, r_prev),)
-                           + res[ri + 1:])
-                    delta_w = jnp.where(comp_mask[dd],
-                                        approx.astype(dtype), delta_w)
-                contrib = ((((wcoef[dd] * e) / denom) * corr)
-                           .astype(dtype)[:, None] * delta_w)
-                tot = jax.ops.segment_sum(contrib, gids[dd],
-                                          num_segments=ngroups[dd])
-                srv_base = srvW[dd] + tot[gids[dd]]
-                if accelerated:
-                    # Nesterov-style server momentum: extrapolate along the
-                    # un-extrapolated combination sequence x_t (= srv_base,
-                    # kept in srvP); the leaves work from the lookahead
-                    # y_t = x_t + acc (x_t - x_{t-1}).  acceleration == 0
-                    # selects srv_base exactly (bit-identical to plain
-                    # SDCA -- a where, not a multiply, so even signed
-                    # zeros survive).
-                    srv_ext = srv_base + acceleration * (srv_base - srvP[dd])
-                    srv_new = jnp.where(acceleration != 0, srv_ext, srv_base)
-                    srvP = srvP.at[dd].set(
-                        jnp.where(act[:, None], srv_base, srvP[dd]))
-                else:
-                    srv_new = srv_base
-                srvW = srvW.at[dd].set(
-                    jnp.where(act[:, None], srv_new, srvW[dd]))
-                w = jnp.where(eb, srv_new, w)
-                act_of[dd] = act
-            # rebase deeper servers onto the shallowest live sync's result
-            # (group-wide, absent leaves included): after a depth-dd pull
-            # the subtree's deeper groups restart from the pulled state
-            for dd in range(D - 1, -1, -1):                   # shallow wins
-                src = srvW[dd]
-                for d2 in range(dd + 1, D):
-                    srvW = srvW.at[d2].set(
-                        jnp.where(act_of[dd][:, None], src, srvW[d2]))
+            with jax.named_scope("leaf_solve"):
+                da, dw = leaf_batch(a, w, keys_s, smask, steps_s)
+                a = a + da
+                w = w + dw
+            # the level syncs, the deeper servers' rebase and the snapshot
+            # refresh: the tick's aggregation up the tree
+            with jax.named_scope("level_sync"):
+                # syncs bottom-up; a leaf with part_s == 0 is absent from every
+                # event of this tick.  `srvW[dd]` is the group's server state;
+                # it advances (and later rebases) GROUP-wide so an absent
+                # leaf's copy stays coherent with its group's.
+                act_of: list = [None] * D
+                for dd in range(D - 1, -1, -1):
+                    ev = sync_s[dd]                               # (n,) event
+                    e = ev * part_s                               # participants
+                    wc = wcoef[dd].astype(dtype)
+                    absent_g = jax.ops.segment_sum(
+                        (ev - e) * wc, gids[dd], num_segments=ngroups[dd])
+                    present_g = jax.ops.segment_sum(
+                        e * wc, gids[dd], num_segments=ngroups[dd])
+                    # exact 1.0 under full participation => x/denom is x/1.0,
+                    # bit-identical to the synchronous path
+                    denom_g = jnp.where(
+                        absent_g == 0, jnp.ones((), dtype),
+                        jnp.where(present_g > 0, present_g,
+                                  jnp.ones((), dtype)))
+                    denom = denom_g[gids[dd]]                     # (n,)
+                    act = (ev > 0) & (present_g > 0)[gids[dd]]    # group live
+                    eb = (e > 0)[:, None]                         # leaf attends
+                    base_a = (snapA[dd] + (ascale[dd] / denom)[:, None]
+                              * (a - snapA[dd]))
                     if accelerated:
-                        # deeper momentum anchors restart from the pulled
-                        # state too (zero velocity after a rebase); the
-                        # alpha anchor restarts from the post-sync alpha
-                        srvP = srvP.at[d2].set(
-                            jnp.where(act_of[dd][:, None], src, srvP[d2]))
-                        srvA = srvA.at[d2].set(
-                            jnp.where(act_of[dd][:, None], a, srvA[d2]))
-            # snapshot refresh is per-leaf private state: participants only.
-            # Depths shallower than the leaf's shallowest attended sync
-            # fast-forward to the server baseline instead: the pulled group
-            # state embeds the CURRENT shallow servers (a re-joining leaf's
-            # next shallow delta must not re-deliver content the server
-            # already has).  Under full participation srvW == snapW, so the
-            # fast-forward is a bitwise no-op.
-            refb = ((ref_s * part_s[None, :]) > 0)[..., None]  # (D, n, 1)
-            attended = ((jnp.max(sync_s, axis=0) * part_s) > 0)  # (n,)
-            ffwd = jnp.logical_not(refb) & attended[None, :, None]
-            snapA = jnp.where(refb, a[None], snapA)
-            snapW = jnp.where(refb, w[None],
-                             jnp.where(ffwd, srvW, snapW))
+                        # extrapolate alpha along its own combined sequence with
+                        # the SAME coefficient as the server w below: w is the
+                        # linear image X^T alpha / (lambda m) of alpha, so a
+                        # shared extrapolation keeps the primal-dual pair
+                        # consistent (momentum on w alone would decouple them)
+                        ext_a = base_a + acceleration * (base_a - srvA[dd])
+                        new_a = jnp.where(acceleration != 0, ext_a, base_a)
+                        srvA = srvA.at[dd].set(jnp.where(eb, base_a, srvA[dd]))
+                        a = jnp.where(eb, new_a, a)
+                    else:
+                        a = jnp.where(eb, base_a, a)
+                    # a partially-present child is represented by its surviving
+                    # leaves (all carrying the child's full delta), so their
+                    # per-leaf coefficients scale up by |child| / |present|;
+                    # fully-present children multiply by exactly 1.0
+                    cnt_c = jax.ops.segment_sum(e, cids[dd],
+                                                num_segments=nchildren[dd])
+                    corr = (csize[dd]
+                            / jnp.maximum(cnt_c, 1.0)[cids[dd]]).astype(dtype)
+                    delta_w = w - snapW[dd]
+                    if dd in comp_idx:
+                        # error feedback: compress(delta + residual); the
+                        # residual advances only for leaves that actually
+                        # deliver at this event (e > 0)
+                        ri = comp_idx[dd]
+                        r_prev = res[ri]
+                        target = delta_w.astype(jnp.float32) + r_prev
+                        approx = roundtrip(dd, target)
+                        e_col = (e > 0)[:, None]
+                        res = (res[:ri]
+                               + (jnp.where(e_col, target - approx, r_prev),)
+                               + res[ri + 1:])
+                        delta_w = jnp.where(comp_mask[dd],
+                                            approx.astype(dtype), delta_w)
+                    contrib = ((((wcoef[dd] * e) / denom) * corr)
+                               .astype(dtype)[:, None] * delta_w)
+                    tot = jax.ops.segment_sum(contrib, gids[dd],
+                                              num_segments=ngroups[dd])
+                    srv_base = srvW[dd] + tot[gids[dd]]
+                    if accelerated:
+                        # Nesterov-style server momentum: extrapolate along the
+                        # un-extrapolated combination sequence x_t (= srv_base,
+                        # kept in srvP); the leaves work from the lookahead
+                        # y_t = x_t + acc (x_t - x_{t-1}).  acceleration == 0
+                        # selects srv_base exactly (bit-identical to plain
+                        # SDCA -- a where, not a multiply, so even signed
+                        # zeros survive).
+                        srv_ext = srv_base + acceleration * (
+                            srv_base - srvP[dd])
+                        srv_new = jnp.where(acceleration != 0, srv_ext,
+                                            srv_base)
+                        srvP = srvP.at[dd].set(
+                            jnp.where(act[:, None], srv_base, srvP[dd]))
+                    else:
+                        srv_new = srv_base
+                    srvW = srvW.at[dd].set(
+                        jnp.where(act[:, None], srv_new, srvW[dd]))
+                    w = jnp.where(eb, srv_new, w)
+                    act_of[dd] = act
+                # rebase deeper servers onto the shallowest live sync's result
+                # (group-wide, absent leaves included): after a depth-dd pull
+                # the subtree's deeper groups restart from the pulled state
+                for dd in range(D - 1, -1, -1):                   # shallow wins
+                    src = srvW[dd]
+                    for d2 in range(dd + 1, D):
+                        srvW = srvW.at[d2].set(
+                            jnp.where(act_of[dd][:, None], src, srvW[d2]))
+                        if accelerated:
+                            # deeper momentum anchors restart from the pulled
+                            # state too (zero velocity after a rebase); the
+                            # alpha anchor restarts from the post-sync alpha
+                            srvP = srvP.at[d2].set(
+                                jnp.where(act_of[dd][:, None], src, srvP[d2]))
+                            srvA = srvA.at[d2].set(
+                                jnp.where(act_of[dd][:, None], a, srvA[d2]))
+                # snapshot refresh is per-leaf private state: participants only.
+                # Depths shallower than the leaf's shallowest attended sync
+                # fast-forward to the server baseline instead: the pulled group
+                # state embeds the CURRENT shallow servers (a re-joining leaf's
+                # next shallow delta must not re-deliver content the server
+                # already has).  Under full participation srvW == snapW, so the
+                # fast-forward is a bitwise no-op.
+                refb = ((ref_s * part_s[None, :]) > 0)[..., None]  # (D, n, 1)
+                attended = ((jnp.max(sync_s, axis=0) * part_s) > 0)  # (n,)
+                ffwd = jnp.logical_not(refb) & attended[None, :, None]
+                snapA = jnp.where(refb, a[None], snapA)
+                snapW = jnp.where(refb, w[None],
+                                 jnp.where(ffwd, srvW, snapW))
             if record_history:
                 out = jax.lax.cond(
                     hflag, lambda aw: objective(*aw),

@@ -196,13 +196,16 @@ def build_lm_step(cfg: ModelConfig, optimizer: Optimizer, *,
             total, metrics = transformer.forward_train(cfg, p, batch)
             return total, metrics
 
-        (_, metrics), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params)
-        if with_lr:
-            params, opt_state = optimizer.update(
-                params, grads, opt_state, lr=lr)
-        else:
-            params, opt_state = optimizer.update(params, grads, opt_state)
+        with jax.named_scope("forward_backward"):
+            (_, metrics), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params)
+        with jax.named_scope("optimizer"):
+            if with_lr:
+                params, opt_state = optimizer.update(
+                    params, grads, opt_state, lr=lr)
+            else:
+                params, opt_state = optimizer.update(params, grads,
+                                                     opt_state)
         return params, opt_state, metrics
 
     vstep = jax.vmap(local_step, in_axes=(0, 0, 0, None))
@@ -270,26 +273,29 @@ def build_lm_step(cfg: ModelConfig, optimizer: Optimizer, *,
         cum = jnp.cumprod(periods.astype(jnp.int32)) if L else None
         mask = participation
 
-        for level in range(L):
-            is_outer = level == L - 1
-            due = (step_no % cum[level]) == 0
+        # the per-level syncs due at this step, the compressed outer one
+        # included
+        with jax.named_scope("tree_sync"):
+            for level in range(L):
+                is_outer = level == L - 1
+                due = (step_no % cum[level]) == 0
 
-            if is_outer and use_comp:
-                def do(ps, os, res):
-                    ps, res = compressed_outer_sync(ps, res, mask)
-                    return ps, os, res
+                if is_outer and use_comp:
+                    def do(ps, os, res):
+                        ps, res = compressed_outer_sync(ps, res, mask)
+                        return ps, os, res
 
-                def skip(ps, os, res):
-                    return ps, os, res
+                    def skip(ps, os, res):
+                        return ps, os, res
 
-                params, opt_state, residual = jax.lax.cond(
-                    due, do, skip, params, opt_state, residual)
-            else:
-                params, opt_state = jax.lax.cond(
-                    due,
-                    functools.partial(sync_level, mask=mask, level=level),
-                    lambda ps, os: (ps, os),
-                    params, opt_state)
+                    params, opt_state, residual = jax.lax.cond(
+                        due, do, skip, params, opt_state, residual)
+                else:
+                    params, opt_state = jax.lax.cond(
+                        due,
+                        functools.partial(sync_level, mask=mask, level=level),
+                        lambda ps, os: (ps, os),
+                        params, opt_state)
 
         new_state = TreeSyncState(params=params, opt_state=opt_state,
                                   step=step_no, residual=residual)

@@ -274,6 +274,7 @@ def get_mesh_executor(
                    if specs[dd][0] != comp_mod.KIND_NONE]
     comp_idx = {dd: i for i, dd in enumerate(comp_depths)}
 
+    @jax.named_scope("codec")
     def roundtrip_vec(depth, target):
         """The receiver's view of this depth's compressed (d,) delta."""
         kind, frac = specs[depth]
@@ -282,6 +283,7 @@ def get_mesh_executor(
         k = comp_mod.topk_count(target.shape[-1], frac)
         return comp_mod.topk_roundtrip(target, k)
 
+    @jax.named_scope("leaf_solve")
     def leaf_solve(Xs, ys, a, w, k_t, st_t, lm):
         """One Procedure-P call on this shard's (1, m_b) block, drawing the
         tick's coordinates from the replayed per-solve key; ``st_t`` is the
@@ -404,6 +406,7 @@ def get_mesh_executor(
             res = res[:ri] + (r_new,) + res[ri + 1:]
             return approx.astype(dt), res
 
+        @jax.named_scope("level_sync")
         def sync_psum(depth, carry, parent_sync):
             """The depth-`depth` aggregation at tick ``t_c - 1`` with
             participation-renormalized weights; absent shards keep their
@@ -465,6 +468,7 @@ def get_mesh_executor(
                 return a, w, t_c, snapA, snapW, srvW, srvP, srvA, res
             return a, w, t_c, snapA, snapW, srvW, res
 
+        @jax.named_scope("level_sync")
         def sync_rs(depth, carry, parent_sync):
             """The reduce_scatter lowering of the depth sync: reconstruct
             the (group-coherent) snapshot from this depth's server shards,

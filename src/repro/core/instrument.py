@@ -10,7 +10,11 @@ history/timing layer.
 * batched histories: the sweep layer (``repro.api.sweep``) stores a config
   batch's series as ``(B, T)`` arrays -- :func:`stack_histories` /
   :func:`history_row` convert between that schema and the per-run dict
-  lists (NaN-padded where members recorded fewer rounds).
+  lists (NaN-padded where members recorded fewer rounds);
+* program spans: :func:`span` marks a host stage of a run on the
+  profiler's own clock (:data:`SPAN_NAMES`), and the traced bodies name
+  their device stages with ``jax.named_scope`` (:data:`SCOPE_NAMES`), so
+  a ``jax.profiler`` trace attributes device time and idle gaps to them.
 """
 from __future__ import annotations
 
@@ -25,6 +29,31 @@ from repro.core.tree import TreeNode
 Array = jax.Array
 
 HISTORY_FIELDS = ("round", "time", "dual", "primal", "gap")
+
+SPAN_PREFIX = "repro:"
+# host spans (each recorded as SPAN_PREFIX + name).  Session.run and
+# LMSession.step enclose the others of their class; the per-round ones
+# carry a ``round`` (``step``) stat
+SPAN_NAMES = (
+    "Session.run", "Session.start_state", "Session.operands",
+    "Session.key_plan", "Session.key_upload", "Session.dispatch",
+    "Session.record", "Session.advance_key", "Session.materialize",
+    "LMSession.step", "LMSession.batch", "LMSession.dispatch",
+    "LMSession.loss_read",
+)
+# device scopes (``jax.named_scope``) of the chunk programs and the LM
+# step, innermost first: an op nested in several counts in the first
+SCOPE_NAMES = ("codec", "level_sync", "leaf_solve", "reblock", "objective",
+               "forward_backward", "optimizer", "tree_sync")
+
+
+def span(name: str, **stats) -> jax.profiler.TraceAnnotation:
+    """A host span ``repro:<name>`` on the profiler's clock (one of
+    :data:`SPAN_NAMES`).  ``stats`` are small ints (round numbers, counts)
+    recorded as the event's arguments; ``set_metadata`` on the returned
+    span adds more before it closes.  With no profiler active it costs
+    about a microsecond and records nothing."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **stats)
 
 
 @dataclasses.dataclass

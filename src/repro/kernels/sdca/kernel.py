@@ -162,6 +162,7 @@ def sdca_block_kernel(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=max(DEFAULT_SCOPED_VMEM_BYTES, need + 2**20)),
         interpret=interpret,
+        name="sdca",
     )(idx.astype(jnp.int32).reshape(K, 1, H), mask.reshape(K, 1, H),
       y.reshape(K, 1, m_b), (jnp.sum(X * X, axis=2) / lm).reshape(K, 1, m_b),
       alpha.reshape(K, 1, m_b), lm_arr, X, w_in)

@@ -419,6 +419,29 @@ def test_lint_wallclock_outside_trace_is_clean(tmp_path):
         """) == []
 
 
+def test_lint_span_in_trace(tmp_path):
+    findings = _lint(tmp_path, """\
+        import jax
+        from repro.core import instrument
+        from repro.core.instrument import span
+
+        @jax.jit
+        def f(x):
+            with span("Session.dispatch"):
+                x = x + 1
+            with instrument.span("Session.record"):
+                x = x * 2
+            with jax.profiler.TraceAnnotation("repro:x"):
+                return x
+
+        def host(g, x):
+            with span("Session.dispatch", round=1):
+                return g(x)
+        """)
+    assert [f.rule for f in findings] == ["span-in-trace"] * 3
+    assert [f.line for f in findings] == [7, 9, 11]
+
+
 def test_lint_jit_location(tmp_path):
     src = """\
         import jax

@@ -84,3 +84,37 @@ def test_rglru_compiles_at_width_2560(spec):
     text = _compiled_text(f, spec((1, 2048, 2560)), spec((1, 2048, 2560)),
                           spec((1, 2560)))
     assert "tpu_custom_call" in text
+
+
+def test_host_chunk_program_names_the_kernel(spec, monkeypatch):
+    """The pallas chunk program, compiled for the chip: its one
+    ``tpu_custom_call`` is the kernel named ``sdca``, under the
+    ``leaf_solve`` scope of the jitted ``solve_fn`` (what the device
+    trace's ``tf_op`` shows)."""
+    import re
+
+    from repro.api import Problem, Session, Topology
+    from repro.core.engine import host as host_mod
+    from repro.core.engine import plan as plan_mod
+
+    m_b, d = 16, 128
+    X = jnp.zeros((8 * m_b, d))
+    y = jnp.ones((8 * m_b,))
+    monkeypatch.setattr(host_mod, "on_tpu", lambda: True)
+    host_mod._EXEC_CACHE.clear()
+    sess = Session.compile(
+        Problem.svm(X, y, lam=0.1, smoothing=1.0),
+        Topology.two_level(2, 4, m_b, group_rounds=2, local_steps=16),
+        backend="pallas")
+    plan = sess.plan
+    S, n, h = plan.n_ticks, plan.n_leaves, plan.h_max
+    text = sess._fn.lower(
+        spec((8 * m_b, d)), spec((8 * m_b,)), spec((S, n, 2), jnp.uint32),
+        spec((8 * m_b,)), spec((d,)), spec((S, n)), spec((S, n, h)),
+        spec(())).compile().as_text()
+    host_mod._EXEC_CACHE.clear()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1
+    assert re.match(r"\s*(ROOT )?%sdca(\.\d+)? = ", calls[0]), calls[0][:200]
+    assert re.search(r'op_name="jit\(solve_fn\)/[^"]*/leaf_solve/sdca/',
+                     calls[0])
