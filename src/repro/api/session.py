@@ -114,6 +114,10 @@ class Session:
         # method with this server-momentum coefficient as the default
         # runtime operand
         self.acceleration = acceleration
+        # how the engine's leaf kernel packs the leaves, for the run span
+        self._kernel_stats = (
+            mesh_mod.kernel_stats(plan, mesh_use_kernel) if backend == "mesh"
+            else host_mod.kernel_stats(plan, backend))
         if backend == "mesh":
             from jax.sharding import NamedSharding, PartitionSpec as P
             spec = P(tuple(reversed(mesh_axes)))
@@ -378,7 +382,8 @@ class Session:
         T = self.resolved.rounds if rounds is None else int(rounds)
         if T < 0:
             raise ValueError(f"rounds must be >= 0, got {T}")
-        with span("Session.run", rounds=T, backend=self.backend) as sp:
+        with span("Session.run", rounds=T, backend=self.backend,
+                  **self._kernel_stats) as sp:
             return self._run(
                 sp, T, key=key, warm_start=warm_start,
                 record_history=record_history, history_every=history_every,

@@ -219,6 +219,17 @@ def get_host_executor(
     return fn
 
 
+def kernel_stats(plan: TreePlan, backend: str) -> dict:
+    """The ``Session.run`` span stats of the leaf solve ``backend`` runs:
+    on ``pallas``, how the ``sdca`` kernel packs the plan's leaves
+    (:func:`repro.kernels.sdca.kernel.leaf_stats`); empty where the leaves
+    run the XLA reference."""
+    if backend != "pallas":
+        return {}
+    from repro.kernels.sdca.kernel import leaf_stats
+    return leaf_stats(plan.n_leaves, plan.m_b)
+
+
 class StateExecutor(NamedTuple):
     """The state-threading executor triple (see ``get_host_executor``):
     ``init(X, alpha0, w0) -> state``, ``step(X, y, keys, state,

@@ -131,6 +131,17 @@ def mesh_executor_cache_keys() -> list:
     return [_named_key(MESH_KEY_FIELDS, k) for k in _MESH_EXEC_CACHE]
 
 
+def kernel_stats(plan: TreePlan, use_kernel: bool) -> dict:
+    """The ``Session.run`` span stats of the leaf solve: with the kernel,
+    how ``sdca`` packs each device's one leaf
+    (:func:`repro.kernels.sdca.kernel.leaf_stats`); empty where the leaf
+    runs the XLA reference."""
+    if not use_kernel:
+        return {}
+    from repro.kernels.sdca.kernel import leaf_stats
+    return leaf_stats(1, plan.m_b)
+
+
 def _check_plan_mesh(plan: TreePlan, mesh: Mesh, axes: Sequence[str]):
     assert plan.levels is not None, (
         "the mesh backend needs a level-homogeneous plan (balanced tree, "

@@ -1,9 +1,10 @@
 """Pallas TPU kernels for the perf-critical compute layers.
 
-  sdca             -- Procedure P (LocalSDCA) as a single VMEM-resident
-                      kernel: H sequential closed-form coordinate steps with
-                      zero HBM round-trips between steps (the paper's
-                      compute hot spot, TPU-adapted).
+  sdca             -- Procedure P (LocalSDCA) as one kernel: H sequential
+                      closed-form coordinate steps, with leaves packed into
+                      the sublanes of every vreg so each step serves many
+                      leaves, and their drawn rows streamed through VMEM
+                      (the paper's compute hot spot, TPU-adapted).
   flash_attention  -- blocked online-softmax causal/GQA/windowed attention
                       (the LM stack's dominant non-matmul HBM term).
   rglru            -- the RG-LRU diagonal recurrence (Griffin) as a

@@ -97,8 +97,14 @@ def test_flash_vs_model_attention_path():
 # blocked SDCA
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("loss_name", ["squared", "smooth_hinge_1", "hinge"])
-@pytest.mark.parametrize("K,m_b,d,H", [(2, 32, 16, 64), (4, 64, 8, 128),
-                                       (1, 128, 32, 256)])
+@pytest.mark.parametrize("K,m_b,d,H", [
+    (2, 32, 16, 64), (4, 64, 8, 128), (1, 128, 32, 256),
+    # K not a multiple of the 8 leaves a vreg packs: padded leaf slots
+    (3, 16, 8, 48), (9, 16, 8, 48), (13, 8, 16, 40),
+    # whole packs of 8
+    (8, 16, 8, 32), (16, 8, 8, 32),
+    # H = 8 m_b: every coordinate recurs, each draw sees its updated alpha
+    (4, 8, 16, 64)])
 def test_sdca_kernel_matches_ref(loss_name, K, m_b, d, H):
     loss = dual_mod.LOSSES[loss_name]
     key = jax.random.PRNGKey(0)
@@ -187,11 +193,51 @@ def test_sdca_solve_increases_dual_and_converges():
                                rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("per_leaf_w,masked", [(True, True), (False, False)])
-@pytest.mark.parametrize("K,m_b,d,H", [(3, 40, 16, 96), (2, 64, 2000, 100)])
+@pytest.mark.parametrize("per_leaf_w,masked", [(True, True), (False, False),
+                                               (True, "idle_leaf")])
+@pytest.mark.parametrize("K,m_b,d,H", [
+    (3, 40, 16, 96), (2, 64, 2000, 100),
+    (9, 16, 16, 48), (13, 24, 16, 64),         # packs of 8 plus a remainder
+    (8, 16, 16, 32), (16, 8, 16, 32),          # whole packs
+    (5, 8, 16, 64),                            # H = 8 m_b: every draw recurs
+    # one leaf a program: alpha in (8, 128) tiles, two tiles at m_b 1,500
+    (1, 40, 16, 96), (1, 1500, 16, 200), (1, 8, 16, 64)])
 def test_sdca_kernel_bit_identical_to_ref(K, m_b, d, H, per_leaf_w, masked):
     """Interpret mode: the kernel's iterates equal the oracle's bit for bit
-    (smoke width d = 2,000 included); only WHERE they live differs."""
+    (smoke width d = 2,000 included); only WHERE they live differs.  An
+    ``idle_leaf`` has an all-zero step mask and comes back unchanged."""
+    _assert_kernel_bits_equal_ref(K, m_b, d, H, per_leaf_w, masked)
+
+
+@pytest.mark.parametrize("K", [1, 5])
+@pytest.mark.parametrize("budget", ["ROW_CHUNK_BYTES", "ROW_GATHER_BYTES"])
+def test_sdca_kernel_bit_identical_with_padded_steps(monkeypatch, K, budget):
+    """A prime H split into chunks of streamed rows, or into pieces of
+    gathered rows (one call each), leaves padded steps at the end (index
+    m_b, step mask 0): they change nothing, bit for bit."""
+    from repro.kernels.sdca import kernel as kmod
+    m_b, d, H = 24, 16, 97
+    monkeypatch.setattr(kmod, budget, 10 * 4096 if budget ==
+                        "ROW_CHUNK_BYTES" else (20_000 if K == 1 else 100_000))
+    pieces, Hc, C = kmod.step_plan(K, m_b, d, H)
+    assert pieces * C * Hc > H and (pieces > 1 or C > 1)
+    _assert_kernel_bits_equal_ref(K, m_b, d, H, True, True)
+
+
+@pytest.mark.parametrize("K,m_b,want", [
+    (1, 784, (1, 1, 1)), (2, 784, (1, 1, 2)),   # one leaf a program
+    (4, 2048, (4, 4, 4)), (4, 8192, (1, 1, 4)),  # the select outgrows P = 4
+    (8, 8192, (8, 8, 8)), (12, 16, (8, 16, 16)),
+    (512, 784, (8, 32, 512))])                   # dual-epsilon-pallas
+def test_leaf_packing_follows_step_costs(K, m_b, want):
+    """Leaves are packed only where a packed step, whose one-hot select
+    grows with m_b, is cheaper per leaf than a single leaf's step (the
+    shapes on each side were timed on a v5e)."""
+    from repro.kernels.sdca.kernel import leaf_packing
+    assert leaf_packing(K, m_b) == want
+
+
+def _assert_kernel_bits_equal_ref(K, m_b, d, H, per_leaf_w, masked):
     loss = dual_mod.LOSSES["smooth_hinge_1"]
     kx, ky, ka, kw, ki, km = jax.random.split(jax.random.PRNGKey(4), 6)
     X = jax.random.normal(kx, (K, m_b, d))
@@ -201,6 +247,8 @@ def test_sdca_kernel_bit_identical_to_ref(K, m_b, d, H, per_leaf_w, masked):
     idx = jax.random.randint(ki, (K, H), 0, m_b)
     mask = (jax.random.uniform(km, (K, H)) > 0.3).astype(jnp.float32) \
         if masked else None
+    if masked == "idle_leaf":
+        mask = mask.at[K // 2].set(0.0)
     lm = jnp.float32(0.01 * K * m_b)
     da_k, dw_k = jax.jit(lambda *a: sdca_block_kernel(
         *a, loss=loss, lm=lm, step_mask=mask, interpret=True))(
@@ -209,14 +257,46 @@ def test_sdca_kernel_bit_identical_to_ref(K, m_b, d, H, per_leaf_w, masked):
         *a, loss=loss, lm=lm, step_mask=mask))(X, y, alpha, w, idx)
     np.testing.assert_array_equal(np.asarray(da_k), np.asarray(da_r))
     np.testing.assert_array_equal(np.asarray(dw_k), np.asarray(dw_r))
+    if masked == "idle_leaf":
+        assert not np.any(np.asarray(da_k)[K // 2])
+        assert not np.any(np.asarray(dw_k)[K // 2])
+
+
+def test_sdca_kernel_streams_block_over_old_vmem_cap():
+    """A 8,192 x 4,096 leaf block (128 MiB, over the VMEM limit were it
+    resident) runs: the kernel streams the drawn rows, so only a chunk of
+    them and the (R, d) / (R, m_b) blocks take VMEM."""
+    from repro.kernels.sdca.kernel import (
+        SMEM_LIMIT_BYTES, VMEM_LIMIT_BYTES, kernel_bytes)
+    K, m_b, d, H = 1, 8192, 4096, 16
+    assert 4 * m_b * d > VMEM_LIMIT_BYTES
+    vmem, smem = kernel_bytes(K, m_b, d, H)
+    assert vmem < VMEM_LIMIT_BYTES and smem < SMEM_LIMIT_BYTES
+    loss = dual_mod.LOSSES["squared"]
+    kx, ky, kw, ki = jax.random.split(jax.random.PRNGKey(5), 4)
+    X = jax.random.normal(kx, (K, m_b, d))
+    y = jax.random.normal(ky, (K, m_b))
+    alpha = jnp.zeros((K, m_b))
+    w = 0.01 * jax.random.normal(kw, (d,))
+    idx = jax.random.randint(ki, (K, H), 0, m_b)
+    lm = 0.1 * m_b
+    da_k, dw_k = sdca_block_kernel(X, y, alpha, w, idx, loss=loss, lm=lm,
+                                   interpret=True)
+    da_r, dw_r = sdca_block_ref(X, y, alpha, w, idx, loss=loss, lm=lm)
+    assert np.count_nonzero(np.asarray(da_k)) > 0
+    np.testing.assert_allclose(np.asarray(da_k), np.asarray(da_r),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(dw_k), np.asarray(dw_r),
+                               rtol=1e-5, atol=1e-6)
 
 
 def test_sdca_kernel_refuses_block_over_vmem_limit():
-    """A leaf block that cannot fit VMEM raises, naming its bytes -- it is
-    never routed to the reference."""
+    """Leaves whose (R, d) w blocks cannot fit VMEM (a million features)
+    raise, naming their bytes -- they are never routed to the
+    reference."""
     from repro.kernels.sdca.kernel import VMEM_LIMIT_BYTES, kernel_bytes
-    K, m_b, d, H = 1, 8192, 4096, 16
-    need, _ = kernel_bytes(m_b, d, H)
+    K, m_b, d, H = 8, 8, 2**20, 16
+    need, _ = kernel_bytes(K, m_b, d, H)
     assert need > VMEM_LIMIT_BYTES
     shapes = (jax.ShapeDtypeStruct((K, m_b, d), jnp.float32),
               jax.ShapeDtypeStruct((K, m_b), jnp.float32),

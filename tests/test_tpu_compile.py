@@ -91,8 +91,6 @@ def test_host_chunk_program_names_the_kernel(spec, monkeypatch):
     ``tpu_custom_call`` is the kernel named ``sdca``, under the
     ``leaf_solve`` scope of the jitted ``solve_fn`` (what the device
     trace's ``tf_op`` shows)."""
-    import re
-
     from repro.api import Problem, Session, Topology
     from repro.core.engine import host as host_mod
     from repro.core.engine import plan as plan_mod
@@ -113,8 +111,82 @@ def test_host_chunk_program_names_the_kernel(spec, monkeypatch):
         spec((8 * m_b,)), spec((d,)), spec((S, n)), spec((S, n, h)),
         spec(())).compile().as_text()
     host_mod._EXEC_CACHE.clear()
+    _assert_one_sdca_call(text)
+
+
+def _assert_one_sdca_call(text: str, pieces: bool = False) -> None:
+    """The compiled chunk program holds one ``tpu_custom_call``: the kernel
+    named ``sdca``, under the ``leaf_solve`` scope of ``solve_fn`` (with
+    ``pieces``, inside the scan over pieces of the steps there)."""
+    import re
+
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert len(calls) == 1
     assert re.match(r"\s*(ROOT )?%sdca(\.\d+)? = ", calls[0]), calls[0][:200]
-    assert re.search(r'op_name="jit\(solve_fn\)/[^"]*/leaf_solve/sdca/',
-                     calls[0])
+    scan = "while/body/closed_call/" if pieces else ""
+    assert re.search(r'op_name="jit\(solve_fn\)/[^"]*/leaf_solve/' + scan
+                     + 'sdca/', calls[0])
+
+
+V5E_HBM_BYTES = 16 * 2**30
+
+
+def _epsilon_chunk_program(spec, monkeypatch, H, fleet=None):
+    """The ``dual-epsilon-pallas`` chunk program (512 leaves of 784 x 2,000
+    f32, per-leaf w and the runtime step mask) at H local steps, compiled
+    for the chip; with ``fleet``, the batched executor of that many sweep
+    members."""
+    from repro.api import Schedule, Topology
+    from repro.core.engine import host as host_mod
+    from repro.core.engine import plan as plan_mod
+
+    K, m_b, d = 512, 784, 2000
+    resolved = Schedule().resolve(
+        Topology.two_level(4, 128, m_b, group_rounds=2, local_steps=H))
+    plan = plan_mod.compile_tree(resolved.chunk_tree,
+                                 weighting=resolved.weighting,
+                                 compression=resolved.compression)
+    assert plan.n_leaves == K and plan.h_max == H
+    monkeypatch.setattr(host_mod, "on_tpu", lambda: True)
+    host_mod._EXEC_CACHE.clear()
+    fn = host_mod.get_host_executor(
+        plan, loss=dual_mod.LOSSES["smooth_hinge_1"], record_history=False,
+        backend="pallas", batched=fleet is not None)
+    S, m = plan.n_ticks, K * m_b
+    B = () if fleet is None else (fleet,)
+    compiled = fn.lower(
+        spec((m, d)), spec((m,)), spec(B + (S, K, 2), jnp.uint32),
+        spec(B + (m,)), spec(B + (d,)), spec((S, K)), spec(B + (S, K, H)),
+        spec(B)).compile()
+    host_mod._EXEC_CACHE.clear()
+    return compiled
+
+
+def test_host_chunk_program_at_epsilon_shape(spec, monkeypatch):
+    """The ``dual-epsilon-pallas`` chunk program at its real shape (512
+    leaves of 784 x 2,000 f32, H = 784, per-leaf w and the runtime step
+    mask), compiled for the chip: the kernel is still its one
+    ``tpu_custom_call``, and one program's blocks fit the kernel's VMEM
+    limit."""
+    from repro.kernels.sdca.kernel import (
+        VMEM_LIMIT_BYTES, kernel_bytes, step_plan)
+
+    assert kernel_bytes(512, 784, 2000, 784)[0] < VMEM_LIMIT_BYTES
+    _assert_one_sdca_call(
+        _epsilon_chunk_program(spec, monkeypatch, 784).as_text(),
+        pieces=step_plan(512, 784, 2000, 784)[0] > 1)
+
+
+@pytest.mark.parametrize("epochs,fleet", [(4, None), (1, 4)])
+def test_host_chunk_program_fits_hbm(spec, monkeypatch, epochs, fleet):
+    """The kernel's gathered rows stay within ``ROW_GATHER_BYTES`` a call:
+    at epsilon's widths the chunk program of four epochs of local steps (H
+    = 4 m_b), and the batched program of a four-member sweep fleet, still
+    fit one v5e chip's HBM, with the kernel the program's one
+    ``tpu_custom_call``."""
+    compiled = _epsilon_chunk_program(spec, monkeypatch, epochs * 784, fleet)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < V5E_HBM_BYTES, total
+    _assert_one_sdca_call(compiled.as_text(), pieces=True)

@@ -102,6 +102,33 @@ def test_session_run_spans(tmp_path, backend):
     assert int(run[3]["upload_bytes"]) == want
 
 
+@pytest.mark.parametrize("backend,groups,per_group,pack,padded", [
+    ("pallas", 1, 3, 3, 0.0),          # 3 leaves: one program packs all 3
+    ("pallas", 3, 4, 8, 0.25),         # 12 leaves in 2 packs of 8: 4 of 16
+    ("vmap", 3, 4, None, None),        # no kernel, no packing stats
+    ("mesh", 1, 1, 1, 0.0),            # the kernel on a device's one leaf
+    ("mesh_ref", 1, 1, None, None)])   # mesh leaves on the XLA reference
+def test_session_run_span_counts_leaf_packing(tmp_path, backend, groups,
+                                              per_group, pack, padded):
+    m_b = 8
+    kw = ({"backend": "mesh", "mesh_use_kernel": False}
+          if backend == "mesh_ref" else {"backend": backend})
+    sess = Session.compile(
+        _problem(m=groups * per_group * m_b),
+        Topology.two_level(groups, per_group, m_b, group_rounds=1,
+                           local_steps=4), **kw)
+    sess.run(1, key=jax.random.PRNGKey(1))
+    with jax.profiler.trace(str(tmp_path)):
+        sess.run(1, key=jax.random.PRNGKey(1))
+    run = next(s for s in _spans(tmp_path) if s[0] == "Session.run")
+    if pack is None:
+        assert "leaf_pack" not in run[3]
+        assert "leaf_slots_padded" not in run[3]
+    else:
+        assert int(run[3]["leaf_pack"]) == pack
+        assert float(run[3]["leaf_slots_padded"]) == padded
+
+
 def test_session_run_spans_cold_start_records_round_zero(tmp_path):
     sess = Session.compile(_problem(), Topology.two_level(**TOPO))
     sess.run(1, key=jax.random.PRNGKey(1))
