@@ -4,9 +4,10 @@ prints the result line.
 
 Nothing here names a cell.  Under ``benchmarks/chip/``, a configuration is
 ``configs/<name>.json`` (its ``driver`` key picks the family: ``dual`` or
-``lm``), a traffic mix is ``mixes/<name>.json``, a cell's correctness
-limits are ``limits/<workload>.json``, and a per-layer metric is
-``metrics/<name>.py`` with a ``read(ctx)`` function.
+``lm``; an LM configuration's ``reference`` key names its plain reference,
+``chipbench/<name>.py``), a traffic mix is ``mixes/<name>.json``, a cell's
+correctness limits are ``limits/<workload>.json``, and a per-layer metric
+is ``metrics/<name>.py`` with a ``read(ctx)`` function.
 """
 from __future__ import annotations
 
@@ -19,6 +20,7 @@ import os
 import sys
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
 BENCH_DIR = Path(__file__).resolve().parents[1]      # benchmarks/chip
@@ -82,15 +84,45 @@ def load_limits(workload: str, bench_dir: Optional[Path] = None) -> dict:
                       .read_text())["limits"]
 
 
-def load_metric(name: str, bench_dir: Optional[Path] = None) -> Callable:
-    """The ``read(ctx)`` of ``metrics/<name>.py``."""
-    path = _named_file("metrics", name, ".py", bench_dir)
-    mod_name = "chipbench_metric_" + "".join(
-        c if c.isalnum() else "_" for c in name)
+def _exec_file(path: Path, prefix: str, name: str) -> ModuleType:
+    mod_name = prefix + "".join(c if c.isalnum() else "_" for c in name)
     spec = importlib.util.spec_from_file_location(mod_name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_metric(name: str, bench_dir: Optional[Path] = None) -> Callable:
+    """The ``read(ctx)`` of ``metrics/<name>.py``."""
+    path = _named_file("metrics", name, ".py", bench_dir)
+    return _exec_file(path, "chipbench_metric_", name).read
+
+
+REFERENCE_API = ("init", "loss_fn", "flops_per_token")
+
+
+def load_reference(name: str, bench_dir: Optional[Path] = None
+                   ) -> ModuleType:
+    """The plain reference ``chipbench/<name>.py`` that an LM
+    configuration names under ``reference``.  It provides
+    ``init(model, seed)`` (float32 weights in the program's tree layout,
+    made from the seed by the program's recipe), ``loss_fn(params, batch,
+    model, q)`` (the scalar the program's loss reports, ``q`` the
+    control's rounding of matmul inputs) and ``flops_per_token(model,
+    seq)`` (training FLOPs a token needs, counted from shapes)."""
+    path = (bench_dir or BENCH_DIR) / "chipbench" / f"{name}.py"
+    if not (name.isidentifier() and path.is_file()):
+        raise BenchError(f"unknown reference {name!r}: no {name}.py in "
+                         f"chipbench/")
+    if path.resolve().parent == Path(__file__).resolve().parent:
+        mod = importlib.import_module(f"chipbench.{name}")
+    else:
+        mod = _exec_file(path, "chipbench_reference_", name)
+    missing = [f for f in REFERENCE_API if not callable(getattr(mod, f,
+                                                                None))]
+    if missing:
+        raise BenchError(f"reference {name!r} lacks {', '.join(missing)}")
+    return mod
 
 
 def cell_metrics(bench: dict, workload: str, per_layer: bool) -> List[dict]:
@@ -132,6 +164,9 @@ class Outcome:
     failed: int
     memory_peak_bytes: int
     counts: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    # the cell's plain reference module, read by per-layer metrics as
+    # ``ctx["reference"]`` (None where the driver has no named reference)
+    reference: Optional[ModuleType] = None
 
 
 class Run:

@@ -17,9 +17,14 @@ copies of would not fit one chip, so every call here starts from the seed:
   must repeat set-up's exactly, which ties the checked steps to the
   window's own call.
 
-``correct`` compares set-up's readings with the plain reference of
-``lm_ref`` run from the same seed, once the window is over and the
-program's state is freed.
+``correct`` compares set-up's readings with the configuration's plain
+reference run from the same seed, once the window is over and the
+program's state is freed.  The configuration names its reference module,
+``chipbench/<name>.py``, under ``reference`` (``lm_ref``, the dense
+decoder, where it names none); the module makes the weights and the loss,
+and counts the work that ``lm_mfu`` reads (``ctx["reference"]``).  The
+AdamW step, the token stream and the leaf norms are shared by every
+reference (``lm_ref``, ``gen``).
 """
 from __future__ import annotations
 
@@ -32,9 +37,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import gen, lm_ref
+from chipbench import gen, harness, lm_ref
 from chipbench.harness import (Check, CompileCounter, Outcome, Run,
                                memory_peak_bytes)
+
+DEFAULT_REFERENCE = "lm_ref"
+
+
+def reference_of(cfg: dict):
+    """The plain reference module the configuration names."""
+    return harness.load_reference(cfg.get("reference", DEFAULT_REFERENCE))
 
 
 def build_session(cfg: dict, mix: dict, seed: int):
@@ -87,9 +99,9 @@ def reference_run(cfg: dict, mix: dict, seed: int, n_steps: int, q=None):
     """The reference from the seed: the first ``n_steps`` losses, the
     first clipped gradient's leaf norms and the change of each leaf after
     ``n_steps`` steps."""
-    model = cfg["model"]
+    model, ref = cfg["model"], reference_of(cfg)
     with jax.default_matmul_precision("highest"):
-        p0 = jax.jit(lambda: gen.lm_init(model, seed))()
+        p0 = jax.jit(lambda: ref.init(model, seed))()
         params = jax.tree.map(jnp.copy, p0)
         state = lm_ref.adamw_init(params)
         losses, g1 = [], None
@@ -97,8 +109,8 @@ def reference_run(cfg: dict, mix: dict, seed: int, n_steps: int, q=None):
             batch = gen.lm_batch(model["vocab_size"], int(mix["batch"]),
                                  int(mix["seq"]), step, seed)
             params, state, loss, g = lm_ref.train_step(
-                params, state, batch, opt=_opt_tuple(cfg), q=q,
-                model_items=tuple(sorted(model.items())))
+                params, state, batch, loss=ref.loss_fn, opt=_opt_tuple(cfg),
+                q=q, model_items=tuple(sorted(model.items())))
             losses.append(float(loss))
             if g1 is None:
                 g1 = lm_ref.leaf_norms(g)
@@ -191,7 +203,7 @@ def run(r: Run, devices, counter: CompileCounter,
         end_to_end={"tokens_per_s": steps * tokens_per_step / window_s,
                     "setup_s": r.setup_s},
         checks=checks, attempted=steps, failed=failed,
-        memory_peak_bytes=peak,
+        memory_peak_bytes=peak, reference=reference_of(cfg),
         counts={"steps": steps, "window_s": window_s,
                 "tokens": steps * tokens_per_step,
                 "compiles_in_window": counter.count,

@@ -5,8 +5,16 @@ inside a sliding window, SwiGLU MLP, untied output head, mean next-token
 cross-entropy) and AdamW (global-norm clipping at 1, decoupled weight
 decay on matrices only), in float32 at the ``highest`` matmul precision.
 
-``mm`` lets the same code run one precision below the configuration (the
-control): every matmul input is rounded to ``float8_e4m3fn`` first.
+It is the reference of an LM configuration without a ``reference`` key,
+and provides what ``harness.load_reference`` asks of one: ``init`` (the
+dense decoder's weights, ``gen.lm_init``), ``loss_fn`` and
+``flops_per_token`` (``work.lm_flops_per_token``).  The parts every
+architecture shares stay here for the others to use: ``adamw_init``,
+``train_step`` (which takes the architecture's loss), ``leaf_norms`` and
+``diff_norms``.
+
+``q`` lets the same code run one precision below the configuration (the
+control): every matmul input is rounded to that dtype first.
 """
 from __future__ import annotations
 
@@ -17,7 +25,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import gen, work
+
 HI = jax.lax.Precision.HIGHEST
+
+init = gen.lm_init
+flops_per_token = work.lm_flops_per_token
 
 
 def _round(x, dtype):
@@ -99,13 +112,15 @@ def _decayed(path) -> bool:
     return "ln" not in name.rsplit("[", 1)[-1]
 
 
-@functools.partial(jax.jit, static_argnames=("opt", "q", "model_items"),
+@functools.partial(jax.jit,
+                   static_argnames=("loss", "opt", "q", "model_items"),
                    donate_argnums=(0, 1))
-def train_step(params, state, batch, *, opt, q, model_items):
-    """One AdamW step; returns (params, state, loss, clipped grads)."""
+def train_step(params, state, batch, *, loss, opt, q, model_items):
+    """One AdamW step of ``loss(params, batch, model, q)``; returns
+    (params, state, loss value, clipped grads)."""
     model = dict(model_items)
     lr, b1, b2, eps, wd, clip = opt
-    loss, g = jax.value_and_grad(loss_fn)(params, batch, model, q)
+    value, g = jax.value_and_grad(loss)(params, batch, model, q)
     gnorm = jnp.sqrt(sum(jnp.sum(t * t) for t in jax.tree.leaves(g)) + 1e-16)
     g = jax.tree.map(lambda t: t * jnp.minimum(1.0, clip / gnorm), g)
     step = state["step"] + 1
@@ -119,7 +134,7 @@ def train_step(params, state, batch, *, opt, q, model_items):
         return p - lr * ((m / bc1) / (jnp.sqrt(n / bc2) + eps) + d * p)
 
     params = jax.tree_util.tree_map_with_path(upd, params, mu, nu)
-    return params, {"step": step, "mu": mu, "nu": nu}, loss, g
+    return params, {"step": step, "mu": mu, "nu": nu}, value, g
 
 
 # ---------------------------------------------------------------------------

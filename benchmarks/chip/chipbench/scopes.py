@@ -21,12 +21,16 @@ small recorded window:
   ``bench:window`` span gives the window);
 * :class:`Window` answers the metrics' questions over it.
 
-Scope rule: an op belongs to scope ``s`` when a path segment ``s`` (bare,
-or wrapped by a transform as in ``vmap(s)``, never ``jit(s)``) appears in one of its
-``tf_op`` entries; an op in several scopes counts in the first of
-:data:`SCOPES`.  Ops of the chunk program (the one ``chunk_ms`` matches)
-in no scope count as unscoped.  Nested ops (a ``while`` and its body)
-count their self time, so a program's ops sum to its busy time.
+Scope rule: an op names segment ``s`` when a path segment ``s`` (bare,
+or wrapped by a transform as in ``vmap(s)``, never ``jit(s)``) appears in
+one of its ``tf_op`` entries.  The partition: an op belongs to the first
+scope of :data:`SCOPES` that it names, so the scopes' times sum to the
+busy time; ops of the chunk program (the one ``chunk_ms`` matches) in no
+scope count as unscoped.  Segments: :meth:`Window.segment_ms` reads the
+ops that name any given segment, whatever their partition scope, so a
+scope nested in another (``forward_backward/moe``) is read without a
+place in :data:`SCOPES`.  Nested ops (a ``while`` and its body) count
+their self time, so a program's ops sum to its busy time.
 """
 from __future__ import annotations
 
@@ -35,6 +39,7 @@ import functools
 import gzip
 import json
 import re
+import sys
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
@@ -51,13 +56,23 @@ OPS_THREAD, PROGRAMS_THREAD = "XLA Ops", "XLA Modules"
 # a scope's segment, bare or wrapped by the transforms that rename a
 # name stack's segments (``vmap(s)``, ``transpose(jvp(s))``)
 _WRAP = r"(?:(?:vmap|jvp|transpose|pmap|remat|checkpoint)\()*"
-_SCOPE_RE = {s: re.compile(rf"(^|/){_WRAP}{s}\)*(/|:|$)") for s in SCOPES}
+
+
+@functools.lru_cache(maxsize=None)
+def _segment_re(name: str) -> re.Pattern:
+    return re.compile(rf"(^|/){_WRAP}{re.escape(name)}\)*(/|:|$)")
+
+
+def names_segment(tf_op: str, names) -> bool:
+    """Whether ``tf_op`` names any of ``names`` as a path segment."""
+    parts = tf_op.split(";")
+    return any(_segment_re(n).search(p) for n in names for p in parts)
 
 
 def scope_of(tf_op: str) -> Optional[str]:
     """The first scope of :data:`SCOPES` that ``tf_op`` names, or None."""
     for s in SCOPES:
-        if any(_SCOPE_RE[s].search(part) for part in tf_op.split(";")):
+        if names_segment(tf_op, (s,)):
             return s
     return None
 
@@ -176,10 +191,12 @@ class Window:
             "rounds": sum(int(s[3].get("rounds", 0)) for s in whole
                           if s[0] == UNIT_SPANS[0]),
             "steps": sum(1 for s in whole if s[0] == UNIT_SPANS[1])}
-        # per chip: [scope or None, self us, program] and busy intervals
+        # per chip: [scope or None, self us, program, name stack] and busy
+        # intervals; the name stacks repeat a lot, so each is kept once, in
+        # :attr:`stacks` with its partition scope
         self.ops: List[List[tuple]] = []
         self.busy: List[List[Tuple[float, float]]] = []
-        scope_cache: Dict[str, Optional[str]] = {}
+        self.stacks: Dict[str, Optional[str]] = {}
         for d in events["devices"]:
             clipped = []
             for name, ts, dur, tf_op, prog in d["ops"]:
@@ -187,10 +204,11 @@ class Window:
                 if c:
                     clipped.append([name, c[0], c[1] - c[0], tf_op, prog])
             own = _self_times(clipped)
-            for o in clipped:                 # name stacks repeat a lot
-                if o[3] not in scope_cache:
-                    scope_cache[o[3]] = scope_of(o[3])
-            self.ops.append([(scope_cache[o[3]], t, o[4])
+            for o in clipped:
+                o[3] = sys.intern(o[3])
+                if o[3] not in self.stacks:
+                    self.stacks[o[3]] = scope_of(o[3])
+            self.ops.append([(self.stacks[o[3]], t, o[4], o[3])
                              for o, t in zip(clipped, own, strict=True)])
             self.busy.append(_union([(o[1], o[1] + o[2]) for o in clipped]))
         self.spans = [s for s in events["spans"]
@@ -208,13 +226,26 @@ class Window:
         out = []
         for ops in self.ops:
             out.append(1e-3 * sum(
-                t for sc, t, prog in ops if sc == scope and (
+                t for sc, t, prog, _ in ops if sc == scope and (
                     not chunk_only
                     or any(p in prog for p in CHUNK_PROGRAMS))))
         return out
 
     def has_scopes(self) -> bool:
-        return any(sc is not None for ops in self.ops for sc, _, _ in ops)
+        return any(sc is not None for ops in self.ops for sc, *_ in ops)
+
+    def naming(self, names) -> set:
+        """The window's name stacks that name any of ``names`` as a path
+        segment (the scope rule of this module)."""
+        return {st for st in self.stacks if names_segment(st, names)}
+
+    def segment_ms(self, names) -> List[float]:
+        """Device milliseconds per chip of the ops whose name stack names
+        any of ``names`` as a path segment, whatever their partition
+        scope (an op counts once, however many of them it names)."""
+        hit = self.naming(names)
+        return [1e-3 * sum(t for _, t, _, st in ops if st in hit)
+                for ops in self.ops]
 
     # device idle by innermost span ----------------------------------------
     def _labelled(self, prefix: str) -> List[Tuple[float, float, str]]:
@@ -313,6 +344,18 @@ def scope_ms_per(ctx, scopes, per: str, chunk_only=False
         win.scope_ms(sc, chunk_only=chunk_only) for sc in scopes),
         strict=True)]
     return max(per_chip) / win.units[per]
+
+
+def segment_ms_per(ctx, names, per: str) -> Optional[float]:
+    """Device ms per ``per`` (as in :func:`scope_ms_per`) of the ops whose
+    name stack names any of ``names`` as a path segment, nested in a
+    partition scope or not (:meth:`Window.segment_ms`), on the busiest
+    chip; None where the trace has no unit span of the program or no op
+    names such a segment."""
+    win = window_of(ctx)
+    if win is None or not win.units[per] or not win.naming(names):
+        return None
+    return max(win.segment_ms(names)) / win.units[per]
 
 
 def idle_ms_per(ctx, names, per: str) -> Optional[float]:

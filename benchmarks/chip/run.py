@@ -53,6 +53,8 @@ def prepare(args):
     if config.get("driver") not in DRIVERS:
         raise harness.BenchError(
             f"configuration {wl['config']!r} names no driver of {DRIVERS}")
+    if "reference" in config:
+        harness.load_reference(config["reference"])
     mix = harness.load_mix(wl["traffic"])
     limits = harness.load_limits(wl["name"])
     metrics = harness.cell_metrics(bench, wl["name"], per_layer=bool(
@@ -96,7 +98,8 @@ def execute(args, *, t_start=T_PROCESS, devices=None, peaks=None,
         device["busy_s"] = summary.busy_s
         device["window_s"] = summary.window_s
         ctx = {"trace": summary, "counts": out.counts, "config": config,
-               "mix": mix, "peaks": peaks, "workload": wl}
+               "mix": mix, "peaks": peaks, "workload": wl,
+               "reference": out.reference}
         values = {}
         for m in metrics:
             v = readers[m["name"]](ctx)

@@ -45,6 +45,7 @@ def test_every_cell_finds_its_pieces_by_name(wl):
     (harness.load_mix, "no-such-mix"),
     (harness.load_limits, "no-such-cell"),
     (harness.load_metric, "no_such_metric"),
+    (harness.load_reference, "no_such_reference"),
     (harness.load_peaks, "TPU v0 imaginary"),
 ])
 def test_unknown_names_are_refused(finder, name):

@@ -322,3 +322,87 @@ def test_metrics_on_a_trace_without_window_or_chip_report_nothing(
         ctx = {"workload": {"name": "cell", "chips": 1},
                "counts": {"rounds": 1}}
         assert harness.load_metric("session_idle_ms")(ctx) is None
+
+
+# ---------------------------------------------------------------------------
+# nested scopes: read by segment, the partition left as it is
+# ---------------------------------------------------------------------------
+FB, STEP = "jit(step)/vmap(forward_backward)/", "jit_step(3)"
+# one chip, one LM step; self times (us): the expert FFN 50 forward and 30
+# transposed, attention 40, an op fused from both 15, the rest of the loss
+# 20, the optimizer 25, and 10 under a longer name that is no segment
+NESTED = {
+    "window": [0.0, 1000.0],
+    "spans": [["repro:LMSession.step", 10.0, 900.0, {"step": "1"}]],
+    "devices": [{"name": "/device:TPU:0", "ops": [
+        ["fusion.1", 100.0, 50.0, FB + "jvp()/moe/dot_general:", STEP],
+        ["fusion.2", 200.0, 30.0, FB + "transpose(jvp(moe))/dot_general:",
+         STEP],
+        ["fusion.3", 300.0, 40.0, FB + "attn/dot_general:", STEP],
+        ["fusion.4", 400.0, 20.0, FB + "add:", STEP],
+        ["fusion.5", 500.0, 25.0, "jit(step)/vmap(optimizer)/add:", STEP],
+        ["fusion.6", 600.0, 10.0, "jit(step)/moe_extra/add:", STEP],
+        ["fusion.7", 700.0, 15.0, FB + "attn/add;" + FB + "moe/mul:", STEP],
+    ]}],
+}
+
+
+def _flat(compact: dict) -> dict:
+    """``compact`` with the nested segments taken out of the name stacks."""
+    flat = json.loads(json.dumps(compact))
+    for op in flat["devices"][0]["ops"]:
+        for seg in ("transpose(jvp(moe))/", "moe/", "attn/"):
+            op[3] = op[3].replace(seg, "")
+    return flat
+
+
+def test_nested_segments_leave_the_partition_as_it_is():
+    w, flat = scopes.Window(NESTED), scopes.Window(_flat(NESTED))
+    for sc in scopes.SCOPES + (None,):
+        assert w.scope_ms(sc) == flat.scope_ms(sc), sc
+    assert w.scope_ms("forward_backward")[0] * 1e3 == pytest.approx(155.0)
+    seg = {names: w.segment_ms(names)[0] * 1e3 for names in (
+        ("moe",), ("attn",), ("moe", "attn"), ("forward_backward",),
+        ("optimizer",), ("moe_",), ("step",))}
+    assert seg == pytest.approx({
+        ("moe",): 95.0, ("attn",): 55.0,
+        ("moe", "attn"): 135.0,             # the fused op counts once
+        ("forward_backward",): 155.0, ("optimizer",): 25.0,
+        ("moe_",): 0.0, ("step",): 0.0})    # jit(step) names no segment
+    assert not w.naming(("moe_",)) and len(w.naming(("moe",))) == 3
+
+
+def test_segment_ms_per_reads_a_nested_scope(monkeypatch, tmp_path):
+    monkeypatch.setattr(harness, "TRACE_ROOT", tmp_path)
+    scopes._window.cache_clear()
+    _write(tmp_path, "cell", chrome_trace(NESTED))
+    ctx = {"workload": {"name": "cell", "chips": 1}, "counts": {"steps": 1}}
+    try:
+        assert scopes.segment_ms_per(ctx, ("moe",), "steps") == \
+            pytest.approx(0.095)
+        assert scopes.segment_ms_per(ctx, ("attn",), "steps") == \
+            pytest.approx(0.055)
+        # no op names the segment, or no unit span of that kind: nothing
+        assert scopes.segment_ms_per(ctx, ("router",), "steps") is None
+        assert scopes.segment_ms_per(ctx, ("moe",), "rounds") is None
+        # the partition's metrics read as they did
+        got = {n: harness.load_metric(n)(ctx) for n in
+               ("lm_fwd_bwd_ms", "lm_optimizer_ms")}
+        assert got == pytest.approx({"lm_fwd_bwd_ms": 0.155,
+                                     "lm_optimizer_ms": 0.025})
+    finally:
+        scopes._window.cache_clear()
+
+
+@pytest.mark.parametrize("name,scope", [
+    ("lm_scopes.json.gz", "forward_backward"),
+    ("lm_scopes.json.gz", "optimizer"),
+    ("pallas_scopes.json.gz", "leaf_solve"),
+    ("vmap_scopes.json.gz", "reblock"),
+])
+def test_recorded_window_segment_equals_its_scope(name, scope):
+    """On a window recorded on the chip, a scope that holds no other scope
+    of the partition reads the same as a segment as it does as a scope."""
+    w = scopes.Window(_recorded(name)["events"])
+    assert w.naming((scope,))
+    assert w.segment_ms((scope,)) == w.scope_ms(scope)

@@ -1,11 +1,14 @@
 """The work counts behind ``sdca_roofline``, ``round_mfu`` and ``lm_mfu``
-match hand counts at small shapes."""
+match hand counts at small shapes, and ``lm_mfu`` reads its count from
+the cell's reference."""
 from __future__ import annotations
+
+import json
 
 import pytest
 
-import chipbench_testutil  # noqa: F401  (puts the benchmark on the path)
-from chipbench import work
+import chipbench_testutil as tu
+from chipbench import harness, work
 
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
@@ -60,3 +63,16 @@ def test_danube_4l_flops_per_token():
         + 2560 * 32000
     assert work.lm_flops_per_token(model, 2048) == pytest.approx(
         6 * n + 12 * 4 * 2560 * 1024.5)
+
+
+def test_lm_mfu_reads_the_danube_count_of_its_reference():
+    """``lm_mfu`` on danube's counts through the default reference reads,
+    to the last digit, what it read when it called ``work`` itself."""
+    cfg = json.loads((tu.BENCH_DIR / "configs" / "h2o-danube-1.8b-4l.json")
+                     .read_text())
+    ctx = {"counts": {"model": cfg["model"], "seq": 2048,
+                      "tokens": 117 * 2048, "window_s": 19.9689},
+           "peaks": harness.load_peaks("TPU v5 lite"),
+           "workload": {"chips": 1},
+           "reference": harness.load_reference("lm_ref")}
+    assert harness.load_metric("lm_mfu")(ctx) == 13.91600059367443
